@@ -53,6 +53,7 @@ if __package__ in (None, ""):  # `python benchmarks/bench_recall.py`
 import numpy as np
 
 from benchmarks.common import emit
+from repro.utils.compile_cache import enable_compile_cache
 
 _JSON_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_recall.json")
 
@@ -298,5 +299,6 @@ if __name__ == "__main__":
     ap.add_argument("--out", default=None,
                     help="write results here instead of BENCH_recall.json")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run(quick=not args.full, sizes=args.sizes, out=args.out)

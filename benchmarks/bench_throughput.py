@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import dataset, emit, trainer
+from repro.utils.compile_cache import enable_compile_cache
 
 _JSON_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_throughput.json")
 
@@ -854,6 +855,7 @@ if __name__ == "__main__":
     arm.add_argument("--telemetry", action="store_true",
                      help="run only the telemetry-overhead arm")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.step:
         run_step_only(quick=not args.full)

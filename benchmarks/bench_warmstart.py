@@ -39,4 +39,7 @@ def run(quick: bool = True) -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

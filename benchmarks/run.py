@@ -17,6 +17,10 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         bench_alpha, bench_model_zoo, bench_negatives, bench_order,
         bench_side_info, bench_throughput, bench_warmstart,

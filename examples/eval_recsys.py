@@ -33,6 +33,7 @@ from repro.infer import embed_all_nodes, export_embeddings, load_embeddings
 from repro.retrieval import IVFConfig
 from repro.sampling import EgoConfig, PairConfig, PipelineConfig
 from repro.train import Graph4RecTrainer, TrainerConfig
+from repro.utils.compile_cache import enable_compile_cache
 from repro.walk import WalkConfig
 
 WALK_MODELS = ("deepwalk", "metapath2vec")
@@ -103,6 +104,7 @@ def main() -> None:
     ap.add_argument("--markdown", default=None, help="write rendered table here")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     datasets = args.datasets.split(",")
     models = args.models.split(",")

@@ -11,7 +11,10 @@ from repro.embedding import EmbeddingConfig
 from repro.graph import DistributedGraphEngine, TOY, generate
 from repro.sampling import EgoConfig, PairConfig, PipelineConfig
 from repro.train import Graph4RecTrainer, TrainerConfig
+from repro.utils.compile_cache import enable_compile_cache
 from repro.walk import WalkConfig
+
+enable_compile_cache()
 
 # 1. graph input — synthetic RetailRocket-like multi-behavior graph
 dataset = generate(TOY, seed=0)

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_arch
 from repro.models import transformer as T
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -25,6 +26,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--tokens", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = get_arch(args.arch, reduced=True)
     if spec.kind == "whisper":

@@ -20,6 +20,7 @@ from repro.embedding import EmbeddingConfig, SlotSpec
 from repro.graph import DistributedGraphEngine, SPECS, generate
 from repro.sampling import EgoConfig, PairConfig, PipelineConfig
 from repro.train import Graph4RecTrainer, TrainerConfig, checkpoint
+from repro.utils.compile_cache import enable_compile_cache
 from repro.walk import WalkConfig
 
 WALK_MODELS = ("deepwalk", "metapath2vec")
@@ -98,6 +99,7 @@ def main() -> None:
                          "matrix as sharded npz via train/checkpoint.py")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     ds = generate(SPECS[args.dataset], seed=args.seed)
     # mp backend: hand the trainer the bare graph — the GraphClient

@@ -9,10 +9,12 @@ import time
 
 from repro.embedding import save_table
 from repro.graph import DistributedGraphEngine, TOY, generate
+from repro.utils.compile_cache import enable_compile_cache
 from benchmarks.common import trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ds = generate(TOY, seed=0)
 
     print("== stage 1: metapath2vec pre-training ==")

@@ -71,7 +71,6 @@ def rowwise_adagrad_scatter_update(
     state: RowAdagradState,
     lr: float = 0.1,
     eps: float = 1e-8,
-    use_kernel: bool = False,
 ) -> Tuple[Dict[str, jnp.ndarray], RowAdagradState]:
     """Scatter form: apply the row-wise rule to the touched rows only.
 
@@ -80,21 +79,13 @@ def rowwise_adagrad_scatter_update(
     ``uniq[k]`` are gathered, stepped, and scattered back; PAD slots
     (``uniq[k] < 0``) carry zero grads by construction (no remapped id points
     at them) and are dropped by the scatter, so padded buckets never perturb
-    the table. ``use_kernel`` routes the gather/apply/scatter through the
-    fused Pallas kernel (kernels/row_adagrad.py).
+    the table.
     """
     new_params: Dict[str, jnp.ndarray] = {}
     new_accum: Dict[str, jnp.ndarray] = {}
     for k, p in params.items():
         ids = uniq[k]
         g = sub_grads[k]
-        if use_kernel:
-            from repro.kernels import ops  # late import: kernels are optional
-
-            new_params[k], new_accum[k] = ops.rowwise_adagrad_scatter(
-                p, state.accum[k], ids, g, lr=lr, eps=eps
-            )
-            continue
         acc_rows = gather_rows(state.accum[k], ids) + jnp.mean(
             g * g, axis=-1, keepdims=True
         )

@@ -113,9 +113,8 @@ def unique_pad_ids(
     non-PAD ids in ascending order. ``width`` is ``max(min_bucket, bucket)``
     doubled until it fits, so a caller that persists the width across batches
     recompiles the jitted step at most O(log n) times and then shapes are
-    stable. PADs lead (rather than trail) so scatter consumers that clamp
-    PAD to row 0 perform their benign no-op writes *before* row 0's real
-    update (see kernels/row_adagrad.py).
+    stable. PADs lead (rather than trail), so the real ids keep one fixed
+    position per bucket width.
     """
     arrays = [np.asarray(a).reshape(-1) for a in id_arrays]
     flat = np.concatenate(arrays) if arrays else np.empty(0, np.int64)
@@ -217,20 +216,11 @@ def ps_lookup(
         out = jnp.where(owned[..., None], out, 0.0)
         return jax.lax.psum(out, model_axis)
 
-    mapped = _shard_map(_local, mesh, in_specs=(P(model_axis, None), P()), out_specs=P())
+    mapped = jax.shard_map(
+        _local, mesh=mesh, in_specs=(P(model_axis, None), P()), out_specs=P(),
+        check_vma=False,
+    )
     return mapped(table, jnp.where(ids >= 0, ids, 0)) * (ids >= 0)[..., None]
-
-
-def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-tolerant shard_map: new JAX exposes ``jax.shard_map`` with
-    ``check_vma``; older releases only have the experimental module with
-    ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
 
 
 def embed_nodes(

@@ -1,2 +1,1 @@
 from repro.kernels import ref
-from repro.kernels.row_adagrad import row_adagrad_scatter_pallas

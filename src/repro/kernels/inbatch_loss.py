@@ -32,10 +32,12 @@ def _inbatch_kernel(src_ref, dst_ref, o_ref, *, temperature: float, tp: int, p_v
     col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = jnp.where(col < p_valid, logits, -1e30)
     m = logits.max(axis=-1, keepdims=True)
-    lse = jnp.log(jnp.exp(logits - m).sum(axis=-1)) + m[:, 0]
-    rows = i * tp + jax.lax.broadcasted_iota(jnp.int32, (tp, 1), 0)[:, 0]
-    diag = jnp.take_along_axis(logits, rows[:, None], axis=1)[:, 0]
-    o_ref[...] = lse - diag  # (TP,)
+    lse = jnp.log(jnp.exp(logits - m).sum(axis=-1, keepdims=True)) + m
+    # the positive of row r is column r: a masked row sum, not a gather
+    # (Mosaic has no general in-kernel gather)
+    rows = i * tp + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+    diag = jnp.where(col == rows, logits, 0.0).sum(axis=-1, keepdims=True)
+    o_ref[...] = lse - diag  # (TP, 1)
 
 
 def inbatch_loss_rows_pallas(
@@ -59,8 +61,8 @@ def inbatch_loss_rows_pallas(
             pl.BlockSpec((tp, d), lambda i: (i, 0)),
             pl.BlockSpec((Pp, d), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((tp,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), jnp.float32),
+        out_specs=pl.BlockSpec((tp, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Pp, 1), jnp.float32),
         interpret=interpret,
     )(h_src, h_dst)
-    return out[:P]
+    return out[:P, 0]

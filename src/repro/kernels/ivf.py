@@ -1,31 +1,28 @@
 """Pallas TPU kernel: IVF gather-then-score over packed inverted lists.
 
 The IVF search hot loop is "for each (query, probed cell): fetch that
-cell's packed quantized rows, score them against the query, fold into the
-query's running shortlist". The host-loop version of that is exactly the
-retrieval bug this kernel exists to kill: the list offsets live in scalar
-memory (``PrefetchScalarGridSpec``), so each grid step DMAs its own
-``lpad``-row slice of the int8 code table straight from HBM into a VMEM
-scratch buffer — no per-call upload, no dense (nlist, max_len) padding, no
-(Q, C, d) candidate tensor.
+cell's packed quantized rows and score them against the query". The list
+offsets live in SMEM, so each grid step DMAs its queries' own list slices
+of the int8 code table straight from HBM into VMEM scratch — no per-call
+upload, no dense (nlist, max_len) padding, no (Q, C, d) candidate tensor.
 
-Grid: (Q, nprobe) with the probe axis innermost. The (1, S) output blocks
-for a query map to the same slab for every probe step (the revisited-output
-accumulation pattern shared with kernels/topk.py): initialized at probe 0,
-merged every step, final after the last probe. Scoring is asymmetric: f32
-query x int8 codes x per-row f32 dequant scale — the codes stay int8 in
-HBM and VMEM, and only the ``lpad x d`` working slice is ever dequantized.
+Grid: (Q/TQ, nprobe) over query tiles of TQ = 8 rows (the f32 sublane
+tile). Step (t, p) starts one DMA per query of the tile (the codes of its
+p-th probed list, from the enclosing 8-row HBM tile on — Mosaic slices
+whole tiles only), then scores each landed slab against its query on the
+MXU and writes that query's lane-dense row of the ``(nprobe, Q, Lp)``
+raw-dot slab. The codes stay int8 in HBM and VMEM; only the working
+slab is ever widened to f32.
 
-Tie-break contract: the [running | new chunk] concatenation ranks
-candidates in flat (probe, within-list) order and ``lax.top_k`` keeps the
-first occurrence of a tied value — identical to ``ref.ivf_list_topk_ref``'s
-flat top-k (the conformance oracle). The exact re-rank stage above this
-kernel re-sorts survivors by item id, so the end-to-end lower-id-wins
-contract never depends on probe order.
+XLA finishes each block of queries: per-row dequant scale, the -inf mask
+outside each list's true rows, and the selection (``lax.top_k`` has no
+Mosaic lowering). Slots flatten in (probe, packed row) order, so
+``top_k``'s first-occurrence rule breaks ties exactly like
+``ref.ivf_list_topk_ref``'s flat top-k (the conformance oracle). The exact
+re-rank stage above this kernel re-sorts survivors by item id, so the
+end-to-end lower-id-wins contract never depends on probe order.
 
-On CPU (this container) the kernel runs with interpret=True; on TPU the
-async copies become real HBM->VMEM DMAs overlapped with the VPU scoring of
-the previous probe's slice.
+On CPU the kernel runs with interpret=True.
 """
 from __future__ import annotations
 
@@ -33,70 +30,97 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# python float so the kernel body never captures a traced constant
 NEG_INF = float("-inf")
+# query rows per grid step (f32 sublane tile)
+TILE_Q = 8
+# queries per kernel call: bounds the (nprobe, block, Lp) score slab
+BLOCK_Q = 512
 
 
-def _ivf_list_kernel(
-    starts_ref,  # (Q, P) scalar-prefetch: packed-row offset per (query, probe)
-    lens_ref,  # (Q, P) scalar-prefetch: true list length per (query, probe)
-    q_ref,  # (1, d) query block
+def dma_rows(lpad: int) -> int:
+    """Rows each per-probe DMA moves. Mosaic only slices whole 8-row HBM
+    tiles, so a list's slab starts at its offset rounded down to a tile
+    and spans ``lpad`` plus that misalignment, rounded up to a tile. The
+    code table must carry this many rows of padding past its last list."""
+    return -(-lpad // 8) * 8 + 8
+
+
+def lane_pad(codes: np.ndarray) -> np.ndarray:
+    """Zero-pad the code table's columns to whole 128-lane tiles, once at
+    upload: Mosaic DMAs only whole lane tiles, so a d = 64 int8 row cannot
+    be sliced out of an unpadded table. Zero columns add nothing to a dot."""
+    d = codes.shape[1]
+    return np.pad(codes, ((0, 0), (0, -(-d // 128) * 128 - d)))
+
+
+def _ivf_score_kernel(
+    starts_ref,  # (TQ, P) SMEM: packed-row offset per (query, probe)
+    q_ref,  # (TQ, d) query tile
     codes_ref,  # (Ip, d) int8 code table, HBM/ANY
-    scales_ref,  # (Ip, 1) f32 dequant scales, HBM/ANY
-    os_ref,  # (1, S) running / final shortlist scores
-    or_ref,  # (1, S) running / final shortlist packed-row indices
-    codes_vmem,  # (lpad, d) int8 scratch: the DMA landing slab
-    scales_vmem,  # (lpad, 1) f32 scratch
-    csem,
-    ssem,
+    o_ref,  # (1, TQ, Lp) raw dots of probe p for the tile
+    codes_vmem,  # (TQ, Lp, d) int8 scratch: the DMA landing slabs
+    sems,  # (TQ,) DMA semaphores
     *,
-    lpad: int,
-    shortlist: int,
+    rows: int,
 ):
-    qi = pl.program_id(0)
     p = pl.program_id(1)
+    tq = codes_vmem.shape[0]
+    copies = []
+    for j in range(tq):
+        start = pl.multiple_of(starts_ref[j, p] // 8 * 8, 8)
+        cp = pltpu.make_async_copy(
+            codes_ref.at[pl.ds(start, rows), :],
+            codes_vmem.at[j, pl.ds(0, rows), :],
+            sems.at[j],
+        )
+        cp.start()
+        copies.append(cp)
+    q = q_ref[...].astype(jnp.float32)  # (TQ, d)
+    for j, cp in enumerate(copies):
+        cp.wait()
+        # scratch rows past ``rows`` hold stale data; the caller's length
+        # mask drops every slot past the list's true length
+        o_ref[0, j : j + 1, :] = jax.lax.dot_general(
+            q[j : j + 1], codes_vmem[j].astype(jnp.float32),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # (1, Lp)
 
-    @pl.when(p == 0)
-    def _init():
-        os_ref[...] = jnp.full_like(os_ref, NEG_INF)
-        or_ref[...] = jnp.full_like(or_ref, -1)
 
-    start = starts_ref[qi, p]
-    ln = lens_ref[qi, p]
-    ccp = pltpu.make_async_copy(
-        codes_ref.at[pl.ds(start, lpad), :], codes_vmem, csem
+def _score_block(q, starts, codes, *, lpad, lp, interpret):
+    """(B, d) queries, (B, P) probed offsets -> (P, B, Lp) raw dots."""
+    B, d = q.shape
+    P = starts.shape[1]
+    assert B % TILE_Q == 0, (B, TILE_Q)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=0,
+        grid=(B // TILE_Q, P),
+        in_specs=[
+            pl.BlockSpec((TILE_Q, P), lambda t, p: (t, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((TILE_Q, d), lambda t, p: (t, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, TILE_Q, lp), lambda t, p: (p, t, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((TILE_Q, lp, d), codes.dtype),
+            pltpu.SemaphoreType.DMA((TILE_Q,)),
+        ],
     )
-    scp = pltpu.make_async_copy(
-        scales_ref.at[pl.ds(start, lpad), :], scales_vmem, ssem
-    )
-    ccp.start()
-    scp.start()
-    q = q_ref[...].astype(jnp.float32)[0]  # (d,)
-    ccp.wait()
-    scp.wait()
-    # asymmetric distance: f32 query x int8 codes, per-row dequant scale
-    raw = jnp.dot(
-        codes_vmem[...].astype(jnp.float32), q, preferred_element_type=jnp.float32
-    )  # (lpad,)
-    scores = raw * scales_vmem[...][:, 0]
-    off = jax.lax.broadcasted_iota(jnp.int32, (lpad,), 0)
-    valid = off < ln
-    scores = jnp.where(valid, scores, NEG_INF)
-    rows = jnp.where(valid, start + off, -1)
-
-    all_s = jnp.concatenate([os_ref[0, :], scores])  # (S + lpad,)
-    all_r = jnp.concatenate([or_ref[0, :], rows])
-    best, pos = jax.lax.top_k(all_s, shortlist)
-    os_ref[...] = best[None]
-    or_ref[...] = jnp.take(all_r, pos)[None]
+    return pl.pallas_call(
+        functools.partial(_ivf_score_kernel, rows=dma_rows(lpad)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((P, B, lp), jnp.float32),
+        interpret=interpret,
+    )(starts, q, codes)
 
 
 def ivf_list_topk_pallas(
     queries: jnp.ndarray,  # (Q, d) float32
-    codes: jnp.ndarray,  # (Ip, d) int8; Ip >= max(starts) + lpad (DMA pad)
+    codes: jnp.ndarray,  # (Ip, d) int8; Ip >= max(starts) + dma_rows(lpad)
     scales: jnp.ndarray,  # (Ip, 1) float32
     starts: jnp.ndarray,  # (Q, P) int32
     lengths: jnp.ndarray,  # (Q, P) int32, <= lpad
@@ -105,41 +129,43 @@ def ivf_list_topk_pallas(
     shortlist: int,
     interpret: bool = False,
 ):
-    """Scalar-prefetch-driven gather-then-score -> per-query shortlist.
+    """Gather-then-score kernel + XLA selection -> per-query shortlist.
 
     Returns ((Q, S) f32 approx scores, (Q, S) i32 packed-row indices, -1
-    for empty slots). Contract matches ``ref.ivf_list_topk_ref`` exactly;
-    the builder guarantees the code table carries ``lpad`` rows of zero
-    padding so the fixed-width DMA slice never reads out of bounds.
+    for empty slots). Contract matches ``ref.ivf_list_topk_ref``; the
+    builder guarantees the code table carries ``dma_rows(lpad)`` rows of
+    zero padding so the fixed-width DMA slice never reads out of bounds.
+    Queries run in blocks of ``BLOCK_Q``, so the scored slab stays
+    O(BLOCK_Q * nprobe * lpad) whatever the query count.
     """
     Q, d = queries.shape
     P = starts.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Q, P),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda qi, p, s_ref, l_ref: (qi, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, shortlist), lambda qi, p, s_ref, l_ref: (qi, 0)),
-            pl.BlockSpec((1, shortlist), lambda qi, p, s_ref, l_ref: (qi, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((lpad, d), codes.dtype),
-            pltpu.VMEM((lpad, 1), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    out_s, out_r = pl.pallas_call(
-        functools.partial(_ivf_list_kernel, lpad=lpad, shortlist=shortlist),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, shortlist), jnp.float32),
-            jax.ShapeDtypeStruct((Q, shortlist), jnp.int32),
-        ],
-        interpret=interpret,
-    )(starts.astype(jnp.int32), lengths.astype(jnp.int32), queries, codes, scales)
-    return out_s, out_r
+    dc = codes.shape[1]  # >= d: lane-padded tables score zeros past d
+    lp = -(-dma_rows(lpad) // 128) * 128
+    bq = min(BLOCK_Q, -(-Q // TILE_Q) * TILE_Q)
+    Qp = -(-Q // bq) * bq
+    pad = ((0, Qp - Q), (0, 0))
+    # padded query rows probe nothing (length 0) and are sliced off
+    q = jnp.pad(queries.astype(jnp.float32), ((0, Qp - Q), (0, dc - d)))
+    q = q.reshape(Qp // bq, bq, dc)
+    st = jnp.pad(starts.astype(jnp.int32), pad).reshape(Qp // bq, bq, P)
+    ln = jnp.pad(lengths.astype(jnp.int32), pad).reshape(Qp // bq, bq, P)
+    off = jnp.arange(lp, dtype=jnp.int32)
+    sc = scales.reshape(-1)
+
+    def block(args):
+        qb, sb, lb = args
+        raw = _score_block(qb, sb, codes, lpad=lpad, lp=lp, interpret=interpret)
+        raw = jnp.transpose(raw, (1, 0, 2))  # (B, P, Lp)
+        # slot c of a slab is packed row (start rounded down to a tile) + c
+        rows = (sb // 8 * 8)[:, :, None] + off[None, None, :]
+        rel = rows - sb[:, :, None]
+        valid = (rel >= 0) & (rel < lb[:, :, None])
+        rows = jnp.where(valid, rows, -1)
+        # dequant after the dot, as the oracle does: (codes . q) * scale
+        s = jnp.where(valid, raw * sc[jnp.maximum(rows, 0)], NEG_INF)
+        best, pos = jax.lax.top_k(s.reshape(bq, P * lp), shortlist)
+        return best, jnp.take_along_axis(rows.reshape(bq, P * lp), pos, axis=1)
+
+    s, r = jax.lax.map(block, (q, st, ln))
+    return s.reshape(Qp, shortlist)[:Q], r.reshape(Qp, shortlist)[:Q]

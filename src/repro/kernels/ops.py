@@ -19,7 +19,6 @@ from repro.kernels import ref
 from repro.kernels.flash_attn import flash_attention_pallas
 from repro.kernels.inbatch_loss import inbatch_loss_rows_pallas
 from repro.kernels.ivf import ivf_list_topk_pallas
-from repro.kernels.row_adagrad import row_adagrad_scatter_pallas
 from repro.kernels.seg_aggr import seg_aggr_pallas
 from repro.kernels.topk import chunked_topk_pallas
 from repro.kernels.window_pairs import window_pair_ids_pallas
@@ -79,26 +78,6 @@ def window_pair_ids(paths: jnp.ndarray, positions):
     program, so no jit wrapper here.
     """
     return window_pair_ids_pallas(paths, positions, interpret=_interpret())
-
-
-# ------------------------------------------------------------- row adagrad
-def rowwise_adagrad_scatter(
-    table: jnp.ndarray,
-    accum: jnp.ndarray,
-    ids: jnp.ndarray,
-    grads: jnp.ndarray,
-    lr: float = 0.1,
-    eps: float = 1e-8,
-):
-    """Fused gather/row-wise-AdaGrad/scatter over the touched rows.
-
-    ``ids`` follows the unique-bucket layout (PADs first; see
-    embedding.table.unique_pad_ids). Called from inside the trainer's jitted
-    sparse step, so no jit wrapper here.
-    """
-    return row_adagrad_scatter_pallas(
-        table, accum, ids, grads, lr=lr, eps=eps, interpret=_interpret()
-    )
 
 
 # ------------------------------------------------------------------ seg_aggr

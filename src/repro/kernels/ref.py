@@ -176,31 +176,3 @@ def ivf_list_topk_ref(
         one, (queries, starts, lengths),
         batch_size=min(batch_size, queries.shape[0]),
     )
-
-
-# ------------------------------------------------------------- row adagrad
-def row_adagrad_scatter_ref(
-    table: jnp.ndarray,  # (N, D)
-    accum: jnp.ndarray,  # (N, 1)
-    ids: jnp.ndarray,  # (bucket,) int; PADs (-1) allowed, real ids distinct
-    grads: jnp.ndarray,  # (bucket, D)
-    lr: float = 0.1,
-    eps: float = 1e-8,
-):
-    """Gather/row-AdaGrad/scatter oracle -> updated (table, accum).
-
-    PAD slots (id < 0) are dropped; rows not named in ``ids`` pass through.
-    """
-    N = table.shape[0]
-    ids = ids.astype(jnp.int32)
-    rows = jnp.where(ids >= 0, ids, N)  # OOB -> dropped at scatter
-    safe = jnp.maximum(ids, 0)
-    g = grads
-    new_acc = accum[safe] + jnp.mean(g * g, axis=-1, keepdims=True).astype(
-        accum.dtype
-    )
-    new_row = (table[safe] - lr * g / (jnp.sqrt(new_acc) + eps)).astype(table.dtype)
-    return (
-        table.at[rows].set(new_row, mode="drop"),
-        accum.at[rows].set(new_acc, mode="drop"),
-    )

@@ -10,16 +10,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """Version-tolerant ``jax.make_mesh``: newer JAX accepts ``axis_types``
-    (and ``jax.sharding.AxisType``); older releases have neither, and the
-    default (auto) behavior is what we want anyway — so fall back to plain
-    ``make_mesh`` when the kwarg or the enum is unavailable."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -9,8 +9,8 @@ resident. This module closes the loop with two measured sources:
   all backends (CPU included), but enumeration walks a global registry,
   so it is a *phase-boundary* probe, never a per-step one.
 - :func:`device_memory_stats` — the backend allocator's own counters
-  (``device.memory_stats()``), which exist on real accelerators and
-  return ``None`` on the CPU backend; gated, never required.
+  (``device.memory_stats()``), which exist on real accelerators (and are
+  required there) and return ``None`` on the CPU backend.
 
 :class:`MemoryAccountant` samples those at coarse lifecycle boundaries
 (tables built, fused adjacency resident, steady-state loop, eval) into
@@ -49,28 +49,26 @@ def live_array_bytes() -> int:
 
 
 def device_memory_stats() -> Dict[str, Dict[str, int]]:
-    """Allocator statistics per device, ``{} `` where unsupported.
+    """Allocator statistics per device; ``{}`` on the CPU backend.
 
-    Real accelerator backends report dicts like ``{"bytes_in_use": ...,
+    Accelerator backends report dicts like ``{"bytes_in_use": ...,
     "peak_bytes_in_use": ...}``; the CPU backend returns ``None`` from
-    ``memory_stats()`` and contributes nothing here.
+    ``memory_stats()`` and contributes nothing here. An accelerator that
+    reports nothing is an error, not a missing feature: every device
+    memory number downstream would silently read as absent.
     """
-    out: Dict[str, Dict[str, int]] = {}
-    try:
-        import jax
+    import jax
 
-        for dev in jax.devices():
-            try:
-                stats = dev.memory_stats()
-            except Exception:
-                stats = None
-            if stats:
-                out[str(dev)] = {
-                    k: int(v) for k, v in stats.items()
-                    if isinstance(v, (int, float))
-                }
-    except Exception as e:
-        log.debug("device_memory_stats unavailable: %s", e)
+    out: Dict[str, Dict[str, int]] = {}
+    for dev in jax.devices():
+        stats = dev.memory_stats()
+        if not stats:
+            if dev.platform == "cpu":
+                continue
+            raise RuntimeError(f"{dev} ({dev.platform}) reports no memory_stats()")
+        out[str(dev)] = {
+            k: int(v) for k, v in stats.items() if isinstance(v, (int, float))
+        }
     return out
 
 

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.ivf import dma_rows, lane_pad
 from repro.kernels.ref import ivf_list_topk_ref
 from repro.lint.sanitizer import host_array
 from repro.retrieval.topk import _deterministic_topk_rows
@@ -341,7 +342,10 @@ def _rerank_exact_device(q, s, ids, table, *, k):
     qf = q.astype(jnp.float32)
     masked = jnp.isneginf(s) | (ids < 0)
     vecs = table[jnp.maximum(ids, 0)].astype(jnp.float32)  # (Q, S, d)
-    es = jnp.einsum("qd,qsd->qs", qf, vecs)
+    # exact f32 dots: the TPU's default f32 matmul precision is reduced
+    es = jnp.einsum(
+        "qd,qsd->qs", qf, vecs, precision=jax.lax.Precision.HIGHEST
+    )
     es = jnp.where(masked, -jnp.inf, es)
     by_id = jnp.argsort(jnp.where(ids >= 0, ids, _INT32_MAX), axis=1)
     ids2 = jnp.take_along_axis(ids, by_id, axis=1)
@@ -383,8 +387,8 @@ class IVFIndex:
     centroids: np.ndarray  # (nlist, d) float32
     order: np.ndarray  # (I,) int32 — packed row -> original item id
     offsets: np.ndarray  # (nlist + 1,) int32 CSR bounds into packed rows
-    codes: np.ndarray  # (I + lpad, d) int8 cell-sorted rows (+ DMA pad)
-    scales: np.ndarray  # (I + lpad, 1) float32 per-row dequant scales
+    codes: np.ndarray  # (I + dma_rows(lpad), d) int8 cell-sorted rows (+ DMA pad)
+    scales: np.ndarray  # (I + dma_rows(lpad), 1) float32 per-row dequant scales
     items: np.ndarray  # (I, d) float32 — the exact table (host copy)
     lpad: int = 1  # max list length: fixed per-probe gather width
     # items moved off their argmax cell by hot-cell balancing at build
@@ -401,10 +405,15 @@ class IVFIndex:
 
     def _upload(self) -> None:
         """One-time host->device residency (the only table-sized H2D)."""
+        backend = self.config.backend
+        if backend == "auto":
+            backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+        self._backend = backend
         dp = jax.device_put
         self._dev = {
             "centroids": dp(self.centroids),
-            "codes": dp(self.codes),
+            # the kernel DMAs whole 128-lane tiles: pad columns once, here
+            "codes": dp(lane_pad(self.codes) if backend == "pallas" else self.codes),
             "scales": dp(self.scales),
             "order": dp(self.order),
             "offsets": dp(self.offsets),
@@ -456,10 +465,11 @@ class IVFIndex:
         offsets[1:] = np.cumsum(counts).astype(np.int32)
         lpad = max(1, int(counts.max()))
         codes, scales = _quantize_rows(it[order])
-        # lpad rows of zero padding so the kernel's fixed-width DMA slice
-        # (pl.ds(start, lpad)) never reads past the table
-        codes = np.concatenate([codes, np.zeros((lpad, d), np.int8)])
-        scales = np.concatenate([scales, np.zeros((lpad, 1), np.float32)])
+        # zero padding so the kernel's fixed-width, tile-aligned DMA slice
+        # never reads past the table
+        pad = dma_rows(lpad)
+        codes = np.concatenate([codes, np.zeros((pad, d), np.int8)])
+        scales = np.concatenate([scales, np.zeros((pad, 1), np.float32)])
         return cls(
             config=dataclasses.replace(config, nlist=nlist),
             centroids=cent.astype(np.float32), order=order, offsets=offsets,
@@ -517,20 +527,19 @@ class IVFIndex:
             ex = host_array(exclude, dtype=np.int32)
         budget = nprobe * self.lpad
         if nprobe >= self.config.nlist:
-            shortlist = budget  # exhaustive: every candidate survives
+            # exhaustive: every item lives in exactly one list, so I slots
+            # hold every candidate (the rest of the budget is padding)
+            shortlist = min(budget, I)
         else:
             want = self.config.rerank or max(4 * k, 128)
             shortlist = min(max(want, k) + ex.shape[1], budget)
-        backend = self.config.backend
-        if backend == "auto":
-            backend = "pallas" if jax.default_backend() == "tpu" else "ref"
         dev = self._dev
         dq = jax.device_put(q)
         s, ids, n_scored = _ivf_shortlist(
             dq, jax.device_put(ex), dev["centroids"], dev["codes"],
             dev["scales"], dev["order"], dev["offsets"],
             nprobe=nprobe, shortlist=shortlist, lpad=self.lpad,
-            backend=backend,
+            backend=self._backend,
         )
         kk = min(k, shortlist)
         if self.config.keep_exact_device:

@@ -132,7 +132,11 @@ def _chunked_topk_scan(queries, items3, exclude, *, k, chunk, num_items):
         ci, chunk_items = inp
         best_s, best_i = carry
         base = ci * chunk
-        scores = q32 @ chunk_items.astype(jnp.float32).T  # (Q, chunk)
+        # exact f32 dots: the TPU's default f32 matmul precision is reduced
+        scores = jnp.matmul(
+            q32, chunk_items.astype(jnp.float32).T,
+            precision=jax.lax.Precision.HIGHEST,
+        )  # (Q, chunk)
         gid = base + jnp.arange(chunk, dtype=jnp.int32)
         scores = jnp.where(gid[None, :] < num_items, scores, -jnp.inf)
         # excluded ids owned by this chunk -> -inf via a dropped scatter

@@ -38,8 +38,9 @@ fit (that regime belongs to the multi-process engine anyway).
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -137,10 +138,16 @@ def fused_eligibility(
 class FusedSampler:
     """Device-resident walk->pair->ego sampler with a single jittable entry.
 
-    ``sample(key)`` is a pure function of the PRNG key (all tables are baked
+    ``sample(key)`` is a pure function of the PRNG key (all tables are built
     at construction), so callers can jit it alone or inline it into a larger
     jitted step (the trainer fuses it with the grad step). Shapes are fully
     static: every batch carries exactly ``config.batch_pairs`` pairs.
+
+    A jitted ``sample`` closes over the device tables, which JAX then copies
+    back to the host and embeds in the program as constants — a transfer
+    ``jax.transfer_guard("disallow")`` refuses on an accelerator. Jitted
+    callers take ``tables()`` as an argument and sample through
+    ``with_tables(tables)`` instead.
     """
 
     def __init__(
@@ -243,6 +250,24 @@ class FusedSampler:
                     for s in self.bag_slots
                 }
 
+    def tables(self) -> Dict[str, Any]:
+        """Every array the constructor shipped with ``jax.device_put``, as
+        one pytree."""
+        return {
+            "adj": self._adj, "deg": self._deg, "sched": self._sched,
+            "start_lo": self._start_lo, "start_cnt": self._start_cnt,
+            "spos": self._spos, "dpos": self._dpos,
+            "slot_pad": dict(self._slot_pad), "bag_counts": dict(self._bag_counts),
+        }
+
+    def with_tables(self, tables: Dict[str, Any]) -> "FusedSampler":
+        """A copy that samples from ``tables`` (inside a trace: the jitted
+        function's own arguments) in place of the resident arrays."""
+        out = copy.copy(self)
+        for name, value in tables.items():
+            setattr(out, f"_{name}", value)
+        return out
+
     def device_table_bytes(self) -> int:
         """Measured footprint of the resident device tables.
 
@@ -251,12 +276,7 @@ class FusedSampler:
         gates on once the sampler exists, replacing the shape-derived
         estimate with ground truth.
         """
-        tables = [
-            self._adj, self._deg, self._sched, self._start_lo,
-            self._start_cnt, self._spos, self._dpos,
-            *self._slot_pad.values(), *self._bag_counts.values(),
-        ]
-        return int(sum(int(t.nbytes) for t in tables))
+        return int(sum(int(t.nbytes) for t in jax.tree.leaves(self.tables())))
 
     # ------------------------------------------------------------- stages
     def _slot_values(self, ids: jnp.ndarray) -> Optional[Dict[str, jnp.ndarray]]:

@@ -44,6 +44,7 @@ equivalent).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import queue
 import threading
@@ -152,9 +153,6 @@ class TrainerConfig:
     unique_bucket: int = 0
     # Row-wise AdaGrad accumulator init (shared by both update paths).
     adagrad_init_accum: float = 0.1
-    # Route the row-wise AdaGrad gather/apply/scatter through the fused
-    # Pallas kernel (kernels/row_adagrad.py) instead of XLA gather+scatter.
-    use_kernel_rowopt: bool = False
     # Drain completed on-device losses to host floats every this many steps
     # (keeps only the in-flight tail on device). 0 defers to the end of run.
     loss_fetch_every: int = 64
@@ -662,7 +660,8 @@ class Graph4RecTrainer:
 
     def _make_fused_step(self):
         """Sampling fused INTO the jitted grad step (sampling_backend=
-        "fused"): the batch is produced on device from the PRNG key alone,
+        "fused"): the batch is produced on device from the PRNG key and the
+        sampler's resident tables (arguments, never baked-in constants),
         so one dispatch per step covers walk, pair, ego, forward, backward
         and the update — the host only advances the key. Tables update
         through the dense full-table rule (identical numerics to the
@@ -670,8 +669,8 @@ class Graph4RecTrainer:
         mc = self.model_cfg
         sampler = self._fused_sampler
 
-        def step(params, opt_state, key):
-            batch = sampler.sample(key)
+        def step(params, opt_state, key, tables):
+            batch = sampler.with_tables(tables).sample(key)
             loss, grads = jax.value_and_grad(model_lib.loss_fn)(params, mc, batch)
             updates, opt_state = self.opt.update(grads, opt_state, params)
             params = opt_lib.apply_updates(params, updates)
@@ -712,7 +711,7 @@ class Graph4RecTrainer:
             dense_p = opt_lib.apply_updates(dense_p, d_updates)
             new_touched, touched_state = emb_opt.rowwise_adagrad_scatter_update(
                 touched, g_sub, uniq, row_state,
-                lr=cfg.sparse_lr, eps=1e-8, use_kernel=cfg.use_kernel_rowopt,
+                lr=cfg.sparse_lr, eps=1e-8,
             )
             row_state = emb_opt.RowAdagradState(
                 accum={**row_state.accum, **touched_state.accum}
@@ -899,7 +898,9 @@ class Graph4RecTrainer:
                     p = self._copy_params(params)
                     st = self.opt.init(p)
                     t0 = time.perf_counter()
-                    out = self._fused_step(p, st, keys[i])
+                    out = self._fused_step(
+                        p, st, keys[i], self._fused_sampler.tables()
+                    )
                     device_barrier(out[2])
                     fused_times.append(time.perf_counter() - t0)
                 meas["fused_step_s"] = median(fused_times[1:])
@@ -1057,7 +1058,9 @@ class Graph4RecTrainer:
             # sparse path so a caller-held pytree survives.
             params = self._copy_params(params)
             opt_state = self.opt.init(params)
-            step_fn = self._fused_step
+            step_fn = functools.partial(
+                self._fused_step, tables=self._fused_sampler.tables()
+            )
         elif self._sparse_on:
             # The sparse step donates its param buffers; copy once so a
             # caller-held pytree (e.g. for a later cold-start eval) survives.

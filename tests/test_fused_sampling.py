@@ -412,6 +412,29 @@ class TestFusedTraining:
         assert r1.pairs_seen == 8 * 128
 
     @pytest.mark.quick
+    def test_fused_step_embeds_no_device_array(self, toy_ds):
+        """A device table the jitted step closes over is copied back to
+        the host while tracing and baked into the program as a constant.
+        On a TPU the step loop's transfer guard refuses that copy; the
+        CPU backend does not count it as a transfer, so check the trace."""
+        tr = self._trainer(toy_ds, "fused", steps=1)
+        p = tr.init_params()
+        key = next(tr._fused_batch_iter())[0]
+        closed = jax.make_jaxpr(tr._fused_step)(
+            p, tr.opt.init(p), key, tr._fused_sampler.tables()
+        )
+
+        def device_consts(j):
+            found = [c for c in getattr(j, "consts", ()) if isinstance(c, jax.Array)]
+            for eqn in getattr(j, "jaxpr", j).eqns:
+                for v in eqn.params.values():
+                    if hasattr(v, "eqns") or hasattr(v, "jaxpr"):
+                        found += device_consts(v)
+            return found
+
+        assert [c.shape for c in device_consts(closed)] == []
+
+    @pytest.mark.quick
     def test_over_budget_falls_back_to_host(self, toy_ds, caplog):
         tr = self._trainer(toy_ds, "fused", steps=3, fused_budget_mb=0.0001)
         assert tr._fused_sampler is None  # fell back

@@ -168,9 +168,12 @@ class TestIVFListTopkOracle:
     path compiles."""
 
     def _case(self, seed, Q, P, d, lpad, rows):
+        from repro.kernels.ivf import dma_rows
+
         rng = np.random.default_rng(seed)
-        codes = rng.integers(-127, 128, size=(rows + lpad, d)).astype(np.int8)
-        scales = rng.uniform(0.5, 2.0, size=(rows + lpad, 1)).astype(np.float32)
+        pad = dma_rows(lpad)  # the builder's DMA padding contract
+        codes = rng.integers(-127, 128, size=(rows + pad, d)).astype(np.int8)
+        scales = rng.uniform(0.5, 2.0, size=(rows + pad, 1)).astype(np.float32)
         q = rng.normal(size=(Q, d)).astype(np.float32)
         starts = rng.integers(0, rows, size=(Q, P)).astype(np.int32)
         lens = rng.integers(0, lpad + 1, size=(Q, P)).astype(np.int32)
@@ -199,11 +202,11 @@ class TestIVFListTopkOracle:
     def test_tie_order_matches_flat_probe_order(self):
         # all-equal scores: both paths must keep the flat (probe, within-
         # list) order — the shared contract the exact re-rank builds on
-        from repro.kernels.ivf import ivf_list_topk_pallas
+        from repro.kernels.ivf import dma_rows, ivf_list_topk_pallas
 
         Q, P, d, lpad, rows = 4, 3, 8, 10, 60
-        codes = jax.device_put(np.ones((rows + lpad, d), np.int8))
-        scales = jax.device_put(np.ones((rows + lpad, 1), np.float32))
+        codes = jax.device_put(np.ones((rows + dma_rows(lpad), d), np.int8))
+        scales = jax.device_put(np.ones((rows + dma_rows(lpad), 1), np.float32))
         q = jax.device_put(np.ones((Q, d), np.float32))
         rng = np.random.default_rng(9)
         starts = jax.device_put(rng.integers(0, rows, size=(Q, P)).astype(np.int32))
@@ -222,6 +225,9 @@ class TestIVFListTopkOracle:
         from repro.kernels.ivf import ivf_list_topk_pallas
 
         q, codes, scales, starts, _ = self._case(77, 3, 2, 8, 6, 50)
+        # eighths: every product and partial sum of a dot is exact in f32,
+        # so the scores cannot depend on the dot's accumulation order
+        q = jax.device_put(np.round(np.asarray(q) * 8) / 8)
         # 4 candidates < shortlist 10
         lens = jax.device_put(np.full((3, 2), 2, np.int32))
         s0, r0 = ref.ivf_list_topk_ref(
@@ -235,32 +241,3 @@ class TestIVFListTopkOracle:
         np.testing.assert_array_equal(np.asarray(r0), np.asarray(r1))
         assert np.isneginf(np.asarray(s1)[:, 4:]).all()
         assert (np.asarray(r1)[:, 4:] == -1).all()
-
-
-@pytest.mark.quick
-class TestRowAdagradOracle:
-    """row_adagrad_scatter_pallas against its oracle (P003 pair): distinct
-    real ids, PADs first, untouched rows pass through."""
-
-    def test_matches_ref(self):
-        from repro.kernels.row_adagrad import row_adagrad_scatter_pallas
-
-        N, D, bucket = 64, 16, 12
-        table = rand(30, (N, D), jnp.float32)
-        accum = jnp.full((N, 1), 0.1, jnp.float32)
-        g = rand(31, (bucket, D), jnp.float32)
-        real = np.array([3, 9, 17, 40, 63], np.int32)
-        ids = jnp.asarray(
-            np.concatenate([np.full(bucket - len(real), -1, np.int32), real])
-        )
-        t0, a0 = ref.row_adagrad_scatter_ref(table, accum, ids, g, lr=0.2)
-        t1, a1 = row_adagrad_scatter_pallas(
-            table, accum, ids, g, lr=0.2, interpret=True
-        )
-        np.testing.assert_allclose(np.asarray(t0), np.asarray(t1), atol=1e-6)
-        np.testing.assert_allclose(np.asarray(a0), np.asarray(a1), atol=1e-6)
-        # rows not named in ids are bitwise untouched
-        untouched = np.setdiff1d(np.arange(N), real)
-        np.testing.assert_array_equal(
-            np.asarray(t1)[untouched], np.asarray(table)[untouched]
-        )

@@ -1,7 +1,7 @@
 """Gather→step→scatter sparse training tests: sparse-vs-dense equivalence
 (params + losses after K steps, across model families, slot modes, and the
-ps_lookup/shard_map pull path), padded-bucket edge cases, the fused Pallas
-row-AdaGrad kernel, and the O(batch)-not-O(N) regression guard."""
+ps_lookup/shard_map pull path), padded-bucket edge cases, and the
+O(batch)-not-O(N) regression guard."""
 import dataclasses
 import time
 
@@ -123,13 +123,6 @@ class TestSparseDenseEquivalence:
         np.testing.assert_array_equal(
             np.asarray(res.params["emb/slot:ghost"]), ghost0
         )
-
-    def test_kernel_rowopt_matches(self, ds):
-        """Fused Pallas gather/AdaGrad/scatter == the XLA scatter path."""
-        rs = build_trainer(ds, sparse=True, use_kernel_rowopt=True,
-                           gnn_type=None, steps=6).train()
-        rd = build_trainer(ds, sparse=False, gnn_type=None, steps=6).train()
-        assert_runs_match(rs, rd)
 
 
 class TestPsLookupEquivalence:
