@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -204,10 +205,13 @@ class DistributedGraphEngine:
         self.graph = graph
         self.num_partitions = int(num_partitions)
         self.client_part = int(client_part)  # partition co-located with the caller
+        t0 = time.perf_counter()
         self.partitions = [
             _Partition(p, self.num_partitions, graph, build=build)
             for p in range(self.num_partitions)
         ]
+        # set-up stage seconds; the trainer reports them in its attribution
+        self.setup_stages = {"engine": time.perf_counter() - t0}
         self.stats = EngineStats()
         self.relation_names = graph.relation_names()
         self.num_nodes = graph.num_nodes

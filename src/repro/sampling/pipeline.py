@@ -37,11 +37,14 @@ PAD = -1
 _MAX_EMPTY_ROUNDS = 100
 
 
+_NULL = contextlib.nullcontext()
+
+
 def _phase(timer, name: str):
     """Attribution scope: a ``PhaseTimer.phase`` when a timer is wired
-    (train.attribution), a no-op context otherwise — zero hot-path cost
-    for untimed runs."""
-    return contextlib.nullcontext() if timer is None else timer.phase(name)
+    (train.attribution), a shared no-op context otherwise — untimed runs
+    pay one ``is None`` test and allocate nothing."""
+    return _NULL if timer is None else timer.phase(name)
 
 
 def _concat_egos(parts: Sequence[EgoBatch]) -> Optional[EgoBatch]:
@@ -103,7 +106,8 @@ def make_train_sampler(
     backends: the host pipeline's stream RNG and the fused sampler's
     build-time padded-adjacency subsample. ``timer`` (a
     ``train.attribution.PhaseTimer``) makes the host pipeline record its
-    sampling cost under the "sample" phase; the trainer's auto backend
+    sampling cost under the "sample" phase, with its "walk", "pairs" and
+    "ego" stages nested inside; the trainer's auto backend
     calibration degrades cheap samplers to the serial path from exactly
     this measurement (prefetch pays only when a batch costs more to
     produce than to hand over).
@@ -136,44 +140,43 @@ class SamplePipeline:
         self.rng = np.random.default_rng(seed)
         graph = engine.graph if hasattr(engine, "graph") else engine
         self._node_range = (0, graph.num_nodes)
-        # stats mirrored from ego sampling for RQ5 accounting
-        self.ego_sampling_ops = 0
 
     # ------------------------------------------------------------------ round
     def _round(self) -> Iterator[Tuple[np.ndarray, np.ndarray, Optional[EgoBatch], Optional[EgoBatch]]]:
+        # Stage phases nest in the caller's "sample" and close before the
+        # yield, so none of them bills the consumer's time.
         cfg = self.config
-        paths = self.walker.generate(self.rng, cfg.walks_per_round)
-        pairs = window_pairs(paths, cfg.pair.win_size)
-        if len(pairs) == 0:
-            return
-        self.rng.shuffle(pairs)
-        if cfg.ego is None:
+        with _phase(self.timer, "walk"):
+            paths = self.walker.generate(self.rng, cfg.walks_per_round)
+        with _phase(self.timer, "pairs"):
+            pairs = window_pairs(paths, cfg.pair.win_size)
+            if len(pairs) == 0:
+                return
+            self.rng.shuffle(pairs)
             src, dst = pairs_to_nodes(paths, pairs)
+        if cfg.ego is None:
             yield src, dst, None, None
             return
 
         if cfg.order == "walk_ego_pair":
             # O(L): one ego sample per (path, position); pairs reference them.
-            B, L = paths.shape
+            L = paths.shape[1]
             flat_nodes = paths.reshape(-1)
-            valid = flat_nodes != PAD
-            egos_flat = sample_ego_batch(
-                self.rng, self.engine, np.where(valid, flat_nodes, 0), cfg.ego
-            )
-            self.ego_sampling_ops += int(valid.sum())
-            src_idx = pairs[:, 0] * L + pairs[:, 1]
-            dst_idx = pairs[:, 0] * L + pairs[:, 2]
-            src, dst = pairs_to_nodes(paths, pairs)
-            yield src, dst, egos_flat.take(src_idx), egos_flat.take(dst_idx)
+            with _phase(self.timer, "ego"):
+                egos_flat = sample_ego_batch(
+                    self.rng, self.engine,
+                    np.where(flat_nodes != PAD, flat_nodes, 0), cfg.ego,
+                )
+                src_ego = egos_flat.take(pairs[:, 0] * L + pairs[:, 1])
+                dst_ego = egos_flat.take(pairs[:, 0] * L + pairs[:, 2])
         elif cfg.order == "walk_pair_ego":
             # O(wL): fresh ego sample per pair endpoint (more diversity).
-            src, dst = pairs_to_nodes(paths, pairs)
-            src_ego = sample_ego_batch(self.rng, self.engine, src, cfg.ego)
-            dst_ego = sample_ego_batch(self.rng, self.engine, dst, cfg.ego)
-            self.ego_sampling_ops += len(src) + len(dst)
-            yield src, dst, src_ego, dst_ego
+            with _phase(self.timer, "ego"):
+                src_ego = sample_ego_batch(self.rng, self.engine, src, cfg.ego)
+                dst_ego = sample_ego_batch(self.rng, self.engine, dst, cfg.ego)
         else:
             raise ValueError(f"unknown order {self.config.order!r}")
+        yield src, dst, src_ego, dst_ego
 
     # ---------------------------------------------------------------- batches
     def batches(self, num_batches: int) -> Iterator[TrainBatch]:
@@ -253,10 +256,10 @@ class SamplePipeline:
                     self.rng, len(src), cfg.pair.num_negatives, self._node_range
                 )
                 if cfg.ego is not None:
-                    neg_ego = sample_ego_batch(
-                        self.rng, self.engine, neg_ids.reshape(-1), cfg.ego
-                    )
-                    self.ego_sampling_ops += neg_ids.size
+                    with _phase(self.timer, "ego"):
+                        neg_ego = sample_ego_batch(
+                            self.rng, self.engine, neg_ids.reshape(-1), cfg.ego
+                        )
         return TrainBatch(
             src_ids=src, dst_ids=dst, neg_ids=neg_ids,
             src_ego=src_ego, dst_ego=dst_ego, neg_ego=neg_ego,

@@ -31,11 +31,18 @@ Design constraints (the H001/H002 lint contract):
 Phases:
 
 - ``sample``   — walker + ego sampling rounds (host pipeline, producer side)
+- ``walk``, ``pairs``, ``ego`` — the stages of a round, nested in ``sample``:
+  ``walker.generate``; window pairs and their shuffle; ego sampling
 - ``assemble`` — TrainBatch -> host numpy pytree (dedup/remap/padding)
 - ``batch_wait`` — consumer blocked on the prefetch queue (starvation)
 - ``h2d``      — explicit ``jax.device_put`` staging of a host batch
 - ``dispatch`` — enqueue of the jitted grad step (async)
 - ``loss_fetch`` — draining completed loss scalars to host
+
+Counters (``count``), summed per batch by the producer on the sparse path:
+
+- ``rows.unique`` — non-PAD rows of the node bucket (rows really updated)
+- ``rows.bucket`` — the bucket's width (rows gathered and scattered)
 """
 from __future__ import annotations
 
@@ -45,9 +52,12 @@ import threading
 import time
 from typing import Dict, Iterable, Optional
 
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import DurationRing, Tracer
 
-PHASES = ("sample", "assemble", "batch_wait", "h2d", "dispatch", "loss_fetch")
+PHASES = ("sample", "walk", "pairs", "ego", "assemble", "batch_wait", "h2d",
+          "dispatch", "loss_fetch")
+COUNTERS = ("rows.unique", "rows.bucket")
 
 
 class PhaseTimer:
@@ -73,6 +83,7 @@ class PhaseTimer:
         capacity: int = 8192,
         tracer: Optional[Tracer] = None,
         pulse=None,
+        metrics: Optional[MetricsRegistry] = None,
     ):
         self._cap = int(capacity)
         self._dur: Dict[str, DurationRing] = {
@@ -83,9 +94,17 @@ class PhaseTimer:
         # at every phase exit, so the stall watchdog can tell "steps are
         # slow but phases still move" from "everything froze"
         self._pulse = pulse
+        # counters live in the telemetry's registry when one is wired; the
+        # summary reports what this timer's run added to them
+        registry = metrics if metrics is not None else MetricsRegistry()
+        self._counters = {c: registry.counter(c) for c in COUNTERS}
+        self._base = {c: k.value for c, k in self._counters.items()}
 
     def add(self, name: str, seconds: float) -> None:
         self._dur[name].add(seconds)
+
+    def count(self, name: str, n: int) -> None:
+        self._counters[name].inc(n)
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -114,7 +133,8 @@ class PhaseTimer:
         is the remaining wall time — device execution plus anything not
         instrumented. Producer phases ("sample"/"assemble") overlap device
         compute when prefetching, so their fractions are reported against
-        wall but may legitimately sum past it.
+        wall but may legitimately sum past it. ``counters`` holds what this
+        timer added to its counters, when it added any.
         """
         phases: Dict[str, Dict] = {}
         for p in PHASES:
@@ -128,6 +148,10 @@ class PhaseTimer:
                 entry["frac_of_wall"] = round(tot / wall_s, 4)
             phases[p] = entry
         out: Dict = {"phases": phases}
+        counters = {c: k.value - self._base[c]
+                    for c, k in self._counters.items()}
+        if any(counters.values()):
+            out["counters"] = counters
         if wall_s is not None:
             out["wall_s"] = round(wall_s, 6)
             consumer = ("batch_wait", "h2d", "dispatch", "loss_fetch")

@@ -43,6 +43,7 @@ equivalent).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -206,10 +207,11 @@ class TrainerConfig:
     # jax.device_put/device_get stay legal; the guard is thread-local, so
     # the prefetch producer is covered by lint rule H002 instead.
     sanitize_transfers: bool = True
-    # Record a per-phase time breakdown (sample/assemble/batch_wait/h2d/
-    # dispatch/loss_fetch) into TrainResult.attribution via the sync-free
-    # ring-buffer PhaseTimer (train/attribution.py). Off by default: zero
-    # hot-loop cost beyond a None check.
+    # Record a per-phase time breakdown (sample and its walk/pairs/ego
+    # stages, assemble/batch_wait/h2d/dispatch/loss_fetch), the sparse
+    # row counters and the set-up stages into TrainResult.attribution via
+    # the sync-free ring-buffer PhaseTimer (train/attribution.py). Off by
+    # default: zero hot-loop cost beyond a None check.
     attribution: bool = False
     # Unified telemetry (repro.obs.Telemetry, default None = disabled): span
     # tracing across the step loop, prefetcher, GraphClient rounds, graph
@@ -240,7 +242,8 @@ class TrainResult:
     # Resolved execution plan (sampling backend, prefetch depth, and — when
     # calibrated — the per-phase measurements the choice was made from).
     plan: Optional[Dict] = None
-    # PhaseTimer summary when TrainerConfig.attribution is on.
+    # PhaseTimer summary when TrainerConfig.attribution is on, plus a
+    # "setup" section: {stage: seconds} of the engine and trainer set-up.
     attribution: Optional[Dict] = None
 
 
@@ -463,6 +466,11 @@ class Graph4RecTrainer:
         cfg: TrainerConfig = TrainerConfig(),
     ):
         self.dataset = dataset
+        # Set-up stage seconds ("engine", "fused_tables", "train_pairs"),
+        # always kept: one pair of clock readings per stage.
+        self.setup_stages: Dict[str, float] = dict(
+            getattr(engine, "setup_stages", {})
+        )
         # "mp" backend: move the partitions out of this process. The client
         # reuses the given engine's partitioning, so switching backends never
         # changes sampling semantics; passing a bare HeteroGraph instead
@@ -567,7 +575,8 @@ class Graph4RecTrainer:
         self._memory = None
         self._plan: Optional[Dict] = None
         if cfg.sampling_backend == "fused":
-            ok, why = self._build_fused()
+            with self._setup_stage("fused_tables"):
+                ok, why = self._build_fused()
             if ok:
                 log.info("fused sampling backend active (%s)", why)
             else:
@@ -592,10 +601,26 @@ class Graph4RecTrainer:
         self._sparse_step = jax.jit(
             self._make_sparse_step(), donate_argnums=(0, 1, 2)
         )
-        self._train_pairs = np.concatenate(
-            [np.stack([u, i], 1) for (u, i) in dataset.train_edges.values()],
-            axis=0,
-        )
+        with self._setup_stage("train_pairs"):
+            self._train_pairs = np.concatenate(
+                [np.stack([u, i], 1)
+                 for (u, i) in dataset.train_edges.values()],
+                axis=0,
+            )
+        log.info("set-up stages: %s", ", ".join(
+            f"{k} {v:.3f}s" for k, v in self.setup_stages.items()))
+
+    @contextlib.contextmanager
+    def _setup_stage(self, name: str):
+        """Time one set-up stage into ``setup_stages``; with telemetry
+        wired, also as a ``setup.<name>`` span."""
+        t0 = time.perf_counter_ns()
+        yield
+        dur = time.perf_counter_ns() - t0
+        self.setup_stages[name] = dur * 1e-9
+        if self.cfg.telemetry is not None:
+            self.cfg.telemetry.tracer.add_span(f"setup.{name}", "setup", t0,
+                                               dur)
 
     def _build_fused(self) -> Tuple[bool, str]:
         """Build the fused sampler + combined sample/grad step if the graph
@@ -793,6 +818,12 @@ class Graph4RecTrainer:
                         self.dataset.graph, batch, self.model_cfg,
                         slot_counts=self._slot_counts,
                     )
+            if timer is not None and self._sparse_on:
+                # rows the sparse step gathers and scatters, and how many
+                # of them are real (the bucket is PAD-padded in front)
+                node = host["uniq"]["node"]
+                timer.count("rows.unique", int(np.count_nonzero(node >= 0)))
+                timer.count("rows.bucket", len(node))
             yield host, len(batch.src_ids)
 
     def _fused_batch_iter(self) -> Iterator[Tuple[jax.Array, int]]:
@@ -1014,10 +1045,13 @@ class Graph4RecTrainer:
 
     def train(self, params: Optional[Dict] = None) -> TrainResult:
         cfg = self.cfg
-        params = params if params is not None else self.init_params()
-        plan = self._resolve_plan(params)
         tel = cfg.telemetry
         tracer = tel.tracer if tel is not None else None
+        # The "prologue" span runs from here to the first dispatch; its
+        # children (prologue.*) name the device-idle gaps before step 0.
+        prologue_t0 = time.perf_counter_ns() if tracer is not None else None
+        params = params if params is not None else self.init_params()
+        plan = self._resolve_plan(params)
         # Run-health guardrails (cfg.health = a HealthConfig): the monitor
         # watches beats/pulses from its own watchdog thread and observes
         # only already-drained host losses, so enabling it never changes
@@ -1048,28 +1082,30 @@ class Graph4RecTrainer:
             PhaseTimer(
                 tracer=tracer,
                 pulse=monitor.pulse if monitor is not None else None,
+                metrics=tel.metrics if tel is not None else None,
             )
             if (cfg.attribution or tracer is not None or monitor is not None)
             else None
         )
         use_fused = plan["sampling"] == "fused"
-        if use_fused:
-            # The fused step donates its param buffers; copy like the
-            # sparse path so a caller-held pytree survives.
-            params = self._copy_params(params)
-            opt_state = self.opt.init(params)
-            step_fn = functools.partial(
-                self._fused_step, tables=self._fused_sampler.tables()
-            )
-        elif self._sparse_on:
-            # The sparse step donates its param buffers; copy once so a
-            # caller-held pytree (e.g. for a later cold-start eval) survives.
-            params = self._copy_params(params)
-            opt_state = self._init_sparse_opt_state(params)
-            step_fn = self._sparse_step
-        else:
-            opt_state = self.opt.init(params)
-            step_fn = self._grad_step
+        if use_fused or self._sparse_on:
+            # The fused and sparse steps donate their param buffers; copy
+            # once so a caller-held pytree (e.g. for a later cold-start
+            # eval) survives.
+            with span_scope(tracer, "prologue.params"):
+                params = self._copy_params(params)
+        with span_scope(tracer, "prologue.opt_init"):
+            if use_fused:
+                opt_state = self.opt.init(params)
+                step_fn = functools.partial(
+                    self._fused_step, tables=self._fused_sampler.tables()
+                )
+            elif self._sparse_on:
+                opt_state = self._init_sparse_opt_state(params)
+                step_fn = self._sparse_step
+            else:
+                opt_state = self.opt.init(params)
+                step_fn = self._grad_step
         loss_hist: List[jax.Array] = []  # in-flight on-device tail
         losses: List[float] = []  # drained, completed losses
         pending_drains: List = []  # started async readbacks, FIFO
@@ -1083,45 +1119,51 @@ class Graph4RecTrainer:
         pairs_seen = 0
         steps_done = 0
         prefetcher: Optional[_Prefetcher] = None
-        if use_fused:
-            batch_iter: Iterator = self._fused_batch_iter()
-        else:
-            pipeline = make_train_sampler(
-                self.engine, self.pipe_cfg, backend="host", seed=cfg.seed,
-                timer=timer,
-            )
-            host_iter: Iterator = self._host_batches(
-                pipeline, cfg.num_steps, timer
-            )
-            if depth > 0:
-                prefetcher = _Prefetcher(
-                    host_iter, depth,
-                    queue_gauge=(
-                        tel.metrics.gauge("prefetch.queue_depth")
+        with span_scope(tracer, "prologue.batches"):
+            if use_fused:
+                batch_iter: Iterator = self._fused_batch_iter()
+            else:
+                pipeline = make_train_sampler(
+                    self.engine, self.pipe_cfg, backend="host", seed=cfg.seed,
+                    timer=timer,
+                )
+                host_iter: Iterator = self._host_batches(
+                    pipeline, cfg.num_steps, timer
+                )
+                if depth > 0:
+                    prefetcher = _Prefetcher(
+                        host_iter, depth,
+                        queue_gauge=(
+                            tel.metrics.gauge("prefetch.queue_depth")
+                            if tel is not None else None
+                        ),
+                        telemetry=tel,
+                        health_check=(
+                            monitor.check if monitor is not None else None
+                        ),
+                    )
+                    host_iter = prefetcher
+                batch_iter = _staged_batches(
+                    host_iter, timer, double_buffer=depth > 0,
+                    staged_gauge=(
+                        tel.metrics.gauge("stager.device_batches")
                         if tel is not None else None
                     ),
-                    telemetry=tel,
-                    health_check=(
-                        monitor.check if monitor is not None else None
-                    ),
                 )
-                host_iter = prefetcher
-            batch_iter = _staged_batches(
-                host_iter, timer, double_buffer=depth > 0,
-                staged_gauge=(
-                    tel.metrics.gauge("stager.device_batches")
-                    if tel is not None else None
-                ),
-            )
         if mem is not None:
             # everything long-lived is resident by now: params, opt state,
             # engine shards, and (fused runs) the device sampling tables
-            mem.sample("fused" if use_fused else "tables")
+            with span_scope(tracer, "prologue.memory"):
+                mem.sample("fused" if use_fused else "tables")
         t0 = time.perf_counter()
         if monitor is not None:
             monitor.start()
         try:
             for step, (dev, npairs) in enumerate(batch_iter):
+                if prologue_t0 is not None:
+                    tracer.add_span("prologue", "trainer", prologue_t0,
+                                    time.perf_counter_ns() - prologue_t0)
+                    prologue_t0 = None
                 # Every dispatch runs under the transfer guard: batches were
                 # staged by an explicit device_put (or ARE device values —
                 # fused keys), so any transfer here is a regression.
@@ -1200,7 +1242,8 @@ class Graph4RecTrainer:
             params=params, losses=losses, eval_history=evals,
             wall_time_s=wall, pairs_seen=pairs_seen, plan=dict(plan),
             attribution=(
-                timer.summary(wall, steps_done)
+                {**timer.summary(wall, steps_done),
+                 "setup": dict(self.setup_stages)}
                 if (timer is not None and cfg.attribution) else None
             ),
         )

@@ -14,11 +14,15 @@ import numpy as np
 import pytest
 
 from repro.core import Graph4RecConfig, HeteroGNNConfig
+from repro.core import model as model_lib
 from repro.embedding import EmbeddingConfig
 from repro.graph import DistributedGraphEngine, TOY, generate
+from repro.obs import MetricsRegistry, Tracer
 from repro.sampling import EgoConfig, PairConfig, PipelineConfig
+from repro.sampling.pipeline import SamplePipeline
 from repro.train import Graph4RecTrainer, TrainerConfig
 from repro.train.attribution import (
+    COUNTERS,
     PHASES,
     PhaseTimer,
     measure_handoff_overhead,
@@ -110,6 +114,25 @@ class TestPhaseTimer:
         with phase_scope(t, "h2d"):
             pass
         assert t.summary()["phases"]["h2d"]["count"] == 1
+
+    def test_counters_land_in_the_registry_per_timer(self):
+        """Counts go to the registry the timer is given; a timer's summary
+        holds what it added, and no section when it added nothing."""
+        reg = MetricsRegistry()
+        assert "counters" not in PhaseTimer(metrics=reg).summary()
+        first = PhaseTimer(metrics=reg)
+        first.count("rows.unique", 5)
+        first.count("rows.bucket", 8)
+        second = PhaseTimer(metrics=reg)
+        second.count("rows.unique", 1)
+        second.count("rows.bucket", 8)
+        assert first.summary()["counters"] == {"rows.unique": 6,
+                                               "rows.bucket": 16}
+        assert second.summary()["counters"] == {"rows.unique": 1,
+                                                "rows.bucket": 8}
+        assert reg.summary()["counters"] == {"rows.bucket": 16,
+                                             "rows.unique": 6}
+        assert set(first.summary()["counters"]) == set(COUNTERS)
 
     def test_handoff_probe_and_median(self):
         per_item = measure_handoff_overhead(items=64)
@@ -252,6 +275,102 @@ class TestAttributionInTrainer:
         # fused mode bypasses the host pipeline and the stager entirely
         assert "sample" not in a["phases"]
         assert "h2d" not in a["phases"]
+
+    @pytest.mark.parametrize("backend", ["host", "fused"])
+    def test_setup_section_names_the_stages(self, ds, backend):
+        tr = make_trainer(ds, steps=4, attribution=True, prefetch_batches=2,
+                          sampling_backend=backend)
+        setup = tr.train().attribution["setup"]
+        want = {"engine", "train_pairs"} | (
+            {"fused_tables"} if backend == "fused" else set())
+        assert set(setup) == want
+        assert all(v >= 0.0 for v in setup.values())
+        assert setup == tr.setup_stages
+        assert setup["engine"] == tr.engine.setup_stages["engine"]
+
+    def test_row_counters_match_emitted_batches(self, ds, monkeypatch):
+        """rows.unique / rows.bucket sum, over the emitted batches, the
+        node bucket's real ids and its width."""
+        emitted = []
+        real = model_lib.sparse_host_batch
+
+        def spy(*args, **kw):
+            out = real(*args, **kw)
+            emitted.append(out["uniq"]["node"].copy())
+            return out
+
+        monkeypatch.setattr(model_lib, "sparse_host_batch", spy)
+        res = make_trainer(ds, steps=5, attribution=True, prefetch_batches=2,
+                           sparse_min_rows=0).train()
+        assert len(emitted) == 5
+        assert res.attribution["counters"] == {
+            "rows.unique": sum(int((u >= 0).sum()) for u in emitted),
+            "rows.bucket": sum(len(u) for u in emitted),
+        }
+
+    def test_dense_path_counts_no_rows(self, ds):
+        a = make_trainer(ds, steps=4, attribution=True,
+                         prefetch_batches=2).train().attribution
+        assert "counters" not in a
+
+
+def _spans(tracer, names):
+    return [(s[0], s[2], s[2] + s[3], tid)
+            for tid, _, spans, _ in tracer.threads() for s in spans
+            if s[0] in names]
+
+
+class TestSamplerStages:
+    """walk / pairs / ego, recorded inside the pipeline's "sample" phase."""
+
+    @pytest.mark.parametrize("order,neg_mode", [
+        ("walk_ego_pair", "inbatch"),
+        ("walk_pair_ego", "inbatch"),
+        ("walk_ego_pair", "random"),
+    ])
+    def test_stages_nest_in_sample(self, ds, order, neg_mode):
+        tracer = Tracer()
+        timer = PhaseTimer(tracer=tracer)
+        pc = PipelineConfig(
+            walk=WalkConfig(metapaths=["u2click2i - i2click2u"], walk_len=6),
+            pair=PairConfig(win_size=2, neg_mode=neg_mode, num_negatives=2),
+            ego=EgoConfig(relations=list(RELS), fanouts=[3]),
+            order=order, batch_pairs=64, walks_per_round=16,
+        )
+        eng = DistributedGraphEngine(ds.graph, num_partitions=2)
+        pipe = SamplePipeline(eng, pc, seed=0, timer=timer)
+        assert len(list(pipe.batches(4))) == 4
+        s = timer.summary()
+        stages = ("walk", "pairs", "ego")
+        for p in ("sample",) + stages:
+            assert s["phases"][p]["count"] > 0, p
+        assert (sum(timer.total(p) for p in stages)
+                <= timer.total("sample") + 1e-9)
+        outer = _spans(tracer, {"sample"})
+        inner = _spans(tracer, set(stages))
+        assert len(inner) == sum(s["phases"][p]["count"] for p in stages)
+        for name, t0, t1, tid in inner:
+            assert any(o0 <= t0 and t1 <= o1 and otid == tid
+                       for _, o0, o1, otid in outer), name
+
+    def test_stages_keep_the_stream(self, ds):
+        """Timing the stages changes no sampled id."""
+        def run(timer):
+            pc = PipelineConfig(
+                walk=WalkConfig(metapaths=["u2click2i - i2click2u"],
+                                walk_len=6),
+                pair=PairConfig(win_size=2),
+                ego=EgoConfig(relations=list(RELS), fanouts=[3]),
+                batch_pairs=64, walks_per_round=16,
+            )
+            eng = DistributedGraphEngine(ds.graph, num_partitions=2)
+            return list(SamplePipeline(eng, pc, seed=3,
+                                       timer=timer).batches(3))
+
+        for a, b in zip(run(None), run(PhaseTimer())):
+            np.testing.assert_array_equal(a.src_ids, b.src_ids)
+            for la, lb in zip(a.dst_ego.levels, b.dst_ego.levels):
+                np.testing.assert_array_equal(la, lb)
 
 
 class TestCommittedBenchmarkPins:

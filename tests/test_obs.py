@@ -403,6 +403,59 @@ class TestTrainerTelemetry:
         res = tr.train()
         assert len(res.losses) == 4
 
+    @pytest.mark.parametrize("backend", ["host", "fused"])
+    def test_prologue_spans_when_traced(self, ds, backend):
+        """``prologue`` runs from the top of train() to the first dispatch;
+        its children lie inside it on the step loop's thread."""
+        tel = Telemetry()
+        tr = make_trainer(ds, steps=4, prefetch_batches=2,
+                          sampling_backend=backend, sparse_min_rows=0,
+                          telemetry=tel)
+        tr.train()
+        (tid, _, spans, _), = [t for t in tel.tracer.threads()
+                               if any(s[0] == "prologue" for s in t[2])]
+        by = {}
+        for name, _, t0, dur, _ in spans:
+            by.setdefault(name, []).append((t0, t0 + dur))
+        (p0, p1), = by["prologue"]
+        children = ("prologue.params", "prologue.opt_init",
+                    "prologue.batches", "prologue.memory")
+        for c in children:
+            (c0, c1), = by[c]
+            assert p0 <= c0 and c1 <= p1, c
+        assert p1 <= min(t0 for t0, _ in by["dispatch"])
+
+    def test_setup_stages_are_spans_when_traced_at_construction(self, ds):
+        tel = Telemetry()
+        tr = make_trainer(ds, steps=2, sampling_backend="fused",
+                          telemetry=tel)
+        got = {s[0]: s[3] for _, _, spans, _ in tel.tracer.threads()
+               for s in spans if s[1] == "setup"}
+        assert set(got) == {"setup.fused_tables", "setup.train_pairs"}
+        for name, dur in got.items():
+            stage = name.split(".", 1)[1]
+            assert dur * 1e-9 == pytest.approx(tr.setup_stages[stage])
+
+    def test_off_run_counts_and_traces_nothing(self, ds, monkeypatch):
+        """attribution off and no telemetry: no phase timer, no counter,
+        no span, no attribution (the set-up stages are still kept)."""
+        from repro.obs.metrics import Counter
+        from repro.train import trainer as trainer_mod
+
+        def forbidden(*a, **kw):
+            raise AssertionError("instrumentation ran with telemetry off")
+
+        monkeypatch.setattr(trainer_mod, "PhaseTimer", forbidden)
+        monkeypatch.setattr(Counter, "inc", forbidden)
+        monkeypatch.setattr(Tracer, "add_span", forbidden)
+        for backend in ("host", "fused"):
+            tr = make_trainer(ds, steps=4, prefetch_batches=2,
+                              sampling_backend=backend, sparse_min_rows=0)
+            res = tr.train()
+            assert res.attribution is None
+            assert len(res.losses) == 4
+            assert {"engine", "train_pairs"} <= set(tr.setup_stages)
+
 
 # ----------------------------------------------------------- mp pipeline
 @pytest.mark.mp

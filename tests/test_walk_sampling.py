@@ -136,9 +136,11 @@ class TestPipelineOrders:
             assert b.src_ego.levels[0].shape[0] == 64
 
     def test_ego_first_cheaper(self, ds):
+        # the engine's request counter is the communication cost (§3.6)
         pipe_fast, _ = self._run(ds, "walk_ego_pair")
         pipe_slow, _ = self._run(ds, "walk_pair_ego")
-        assert pipe_fast.ego_sampling_ops < pipe_slow.ego_sampling_ops
+        assert (pipe_fast.engine.stats.neighbor_requests
+                < pipe_slow.engine.stats.neighbor_requests)
 
     def test_pair_endpoints_match_ego_centers(self, ds):
         _, batches = self._run(ds, "walk_ego_pair")
