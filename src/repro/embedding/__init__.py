@@ -4,6 +4,7 @@ from repro.embedding.table import (
     pad_slot_values,
     slot_count_matrix,
     unique_pad_ids, remap_ids, gather_rows, scatter_rows,
+    column_major_default, gather_rows_cm, scatter_rows_cm,
     save_table, load_table, warm_start,
 )
 from repro.embedding.optimizer import (

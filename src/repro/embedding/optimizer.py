@@ -23,7 +23,7 @@ memory of full AdaGrad) — in the two forms the trainer uses:
 """
 from __future__ import annotations
 
-from typing import Dict, Mapping, NamedTuple, Tuple
+from typing import Callable, Dict, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +71,8 @@ def rowwise_adagrad_scatter_update(
     state: RowAdagradState,
     lr: float = 0.1,
     eps: float = 1e-8,
+    rows: Optional[Mapping[str, jnp.ndarray]] = None,
+    scatter: Optional[Mapping[str, Callable]] = None,
 ) -> Tuple[Dict[str, jnp.ndarray], RowAdagradState]:
     """Scatter form: apply the row-wise rule to the touched rows only.
 
@@ -79,7 +81,9 @@ def rowwise_adagrad_scatter_update(
     ``uniq[k]`` are gathered, stepped, and scattered back; PAD slots
     (``uniq[k] < 0``) carry zero grads by construction (no remapped id points
     at them) and are dropped by the scatter, so padded buckets never perturb
-    the table.
+    the table. ``rows`` passes the gathered rows where the caller holds
+    them already, and ``scatter`` a table's own row scatter (default
+    ``scatter_rows``; ``scatter_rows_cm`` for a column-major table).
     """
     new_params: Dict[str, jnp.ndarray] = {}
     new_accum: Dict[str, jnp.ndarray] = {}
@@ -89,7 +93,8 @@ def rowwise_adagrad_scatter_update(
         acc_rows = gather_rows(state.accum[k], ids) + jnp.mean(
             g * g, axis=-1, keepdims=True
         )
-        rows = gather_rows(p, ids) - lr * g / (jnp.sqrt(acc_rows) + eps)
-        new_params[k] = scatter_rows(p, ids, rows)
+        old = rows[k] if rows is not None else gather_rows(p, ids)
+        push = (scatter or {}).get(k, scatter_rows)
+        new_params[k] = push(p, ids, old - lr * g / (jnp.sqrt(acc_rows) + eps))
         new_accum[k] = scatter_rows(state.accum[k], ids, acc_rows)
     return new_params, RowAdagradState(accum=new_accum)

@@ -38,8 +38,12 @@ from typing import Dict, Mapping, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental.layout import Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.kernels import ops
+from repro.kernels.table_rows import main_rows
 from repro.utils.ragged import ragged_row_offsets
 
 
@@ -164,6 +168,61 @@ def scatter_rows(
     """
     idx = jnp.where(uniq >= 0, uniq, table.shape[0])
     return table.at[idx].set(rows, mode="drop")
+
+
+def column_major_default(shape: Sequence[int], dtype, device) -> bool:
+    """Whether ``device``'s backend keeps an f32 (rows, dim) table
+    column-major by default, as a TPU does when dim is under 128 lanes
+    (``{0,1:T(8,128)}`` for dim 64). XLA's row gather and row scatter then
+    copy the whole table to row-major and back; ``gather_rows_cm`` and
+    ``scatter_rows_cm`` read and write the rows where they lie (their
+    kernels take widths that fill whole 8-row sublane tiles). Asks the
+    backend, not the platform's name; a backend that cannot say keeps
+    XLA's row ops."""
+    if len(shape) != 2 or np.dtype(dtype) != np.float32 or shape[1] % 8:
+        return False
+    try:
+        layout = device.client.get_default_layout(
+            np.dtype(dtype), tuple(shape), device)
+    except jax.errors.JaxRuntimeError:
+        return False
+    return Layout.from_pjrt_layout(layout).major_to_minor == (1, 0)
+
+
+def gather_rows_cm(table: jnp.ndarray, uniq: jnp.ndarray) -> jnp.ndarray:
+    """``gather_rows`` for a table kept column-major, bit for bit: the row
+    kernel over the table's transpose (no copy of the table), row 0 for PAD
+    slots as ``gather_rows`` clamps, and XLA's gather for the rows of the
+    last, partial lane block."""
+    n = table.shape[0]
+    n_main = main_rows(n)
+    if n_main == 0:
+        return gather_rows(table, uniq)
+    rows = ops.table_gather_cols(table.T, uniq).T
+    rows = jnp.where((uniq < 0)[:, None], table[0], rows)
+    if n_main < n:
+        at = jnp.clip(uniq - n_main, 0, n - n_main - 1)
+        tail = jnp.take(table[n_main:], at, axis=0)
+        rows = jnp.where((uniq >= n_main)[:, None], tail, rows)
+    return rows
+
+
+def scatter_rows_cm(
+    table: jnp.ndarray, uniq: jnp.ndarray, rows: jnp.ndarray
+) -> jnp.ndarray:
+    """``scatter_rows`` for a table kept column-major: the row kernel writes
+    the rows in place (under donation), and ``scatter_rows`` the rows of
+    the last, partial lane block, into a slice of the kernel's output."""
+    n = table.shape[0]
+    n_main = main_rows(n)
+    if n_main == 0:
+        return scatter_rows(table, uniq, rows)
+    out = ops.table_scatter_cols(table.T, uniq, rows.T).T
+    if n_main < n:
+        at = jnp.where(uniq >= n_main, uniq - n_main, -1)
+        tail = scatter_rows(out[n_main:], at, rows)
+        out = lax.dynamic_update_slice(out, tail, (n_main, 0))
+    return out
 
 
 def slot_count_matrix(

@@ -20,6 +20,7 @@ from repro.kernels.flash_attn import flash_attention_pallas
 from repro.kernels.inbatch_loss import inbatch_loss_rows_pallas
 from repro.kernels.ivf import ivf_list_topk_pallas
 from repro.kernels.seg_aggr import seg_aggr_pallas
+from repro.kernels.table_rows import gather_cols_pallas, scatter_cols_pallas
 from repro.kernels.topk import chunked_topk_pallas
 from repro.kernels.window_pairs import window_pair_ids_pallas
 
@@ -78,6 +79,21 @@ def window_pair_ids(paths: jnp.ndarray, positions):
     program, so no jit wrapper here.
     """
     return window_pair_ids_pallas(paths, positions, interpret=_interpret())
+
+
+# ------------------------------------------------------------ table rows
+def table_gather_cols(table_t: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+    """Columns ``ids`` of a (D, N) table transpose -> (D, B); zeros for PAD
+    ids and ids past ``table_rows.main_rows(N)`` (kernels/table_rows.py)."""
+    return gather_cols_pallas(table_t, ids, interpret=_interpret())
+
+
+def table_scatter_cols(
+    table_t: jnp.ndarray, ids: jnp.ndarray, cols_t: jnp.ndarray
+) -> jnp.ndarray:
+    """``table_t`` with columns ``ids`` set from ``cols_t`` (the output
+    aliases ``table_t``); PAD ids and the tail are skipped."""
+    return scatter_cols_pallas(table_t, ids, cols_t, interpret=_interpret())
 
 
 # ------------------------------------------------------------------ seg_aggr

@@ -176,3 +176,22 @@ def ivf_list_topk_ref(
         one, (queries, starts, lengths),
         batch_size=min(batch_size, queries.shape[0]),
     )
+
+
+# ---------------------------------------------------------------- table rows
+def gather_cols_ref(table_t: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+    """Columns ``ids`` of a (D, N) table transpose -> (D, B); zeros where an
+    id is PAD (< 0) or lies in the last, partial block of 128 rows."""
+    ok = (ids >= 0) & (ids < table_t.shape[1] // 128 * 128)
+    cols = jnp.take(table_t, jnp.where(ok, ids, 0), axis=1)
+    return jnp.where(ok[None, :], cols, 0)
+
+
+def scatter_cols_ref(
+    table_t: jnp.ndarray, ids: jnp.ndarray, cols_t: jnp.ndarray
+) -> jnp.ndarray:
+    """``table_t`` with column ``ids[j]`` set to column j of ``cols_t``; PAD
+    ids and ids in the last, partial block of 128 rows are skipped."""
+    n = table_t.shape[1]
+    ok = (ids >= 0) & (ids < n // 128 * 128)
+    return table_t.at[:, jnp.where(ok, ids, n)].set(cols_t, mode="drop")

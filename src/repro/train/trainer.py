@@ -36,10 +36,13 @@ per embedding table and remaps the batch onto gathered sub-tables
 (core/model.py:sparse_device_batch); the jitted step differentiates w.r.t.
 the gathered rows only, applies row-wise AdaGrad to them, and scatters the
 updated rows back into the donated tables — O(unique ids) per step instead
-of the dense path's O(num_nodes). ``sparse_updates=False`` keeps the dense
-full-table grad step (same row-wise AdaGrad rule via
-train.optimizer.rowwise_adagrad, so the two paths are numerically
-equivalent).
+of the dense path's O(num_nodes). Where the backend keeps a table
+column-major (a TPU, for width 64), the gather and scatter are the row
+kernels of kernels/table_rows.py, which move the rows where they lie;
+XLA's row ops would copy the whole table to row-major and back.
+``sparse_updates=False`` keeps the dense full-table grad step (same
+row-wise AdaGrad rule via train.optimizer.rowwise_adagrad, so the two
+paths are numerically equivalent).
 """
 from __future__ import annotations
 
@@ -240,7 +243,9 @@ class TrainResult:
     wall_time_s: float
     pairs_seen: int
     # Resolved execution plan (sampling backend, prefetch depth, and — when
-    # calibrated — the per-phase measurements the choice was made from).
+    # calibrated — the per-phase measurements the choice was made from);
+    # on the sparse path also "table_rows": how each table's rows move
+    # ("row kernel" or "xla", see ``table_row_ops``).
     plan: Optional[Dict] = None
     # PhaseTimer summary when TrainerConfig.attribution is on, plus a
     # "setup" section: {stage: seconds} of the engine and trainer set-up.
@@ -385,6 +390,23 @@ class _Prefetcher:
             self._c_wedged.inc()
         if self._tracer is not None:
             self._tracer.mark("prefetch.wedged_producer", where=where)
+
+
+def table_row_ops(
+    params: Dict, device
+) -> Dict[str, Tuple[Callable, Callable]]:
+    """Per ``emb/`` table (arrays or shapes): the row gather and row scatter
+    the sparse step moves its rows with. Where ``device``'s backend keeps the
+    table column-major by default (a TPU, for width 64), the row kernels,
+    which read and write the rows where they lie; elsewhere XLA's row ops,
+    whose gather and scatter would otherwise copy the whole table to
+    row-major and back in every step."""
+    return {
+        k: (emb.gather_rows_cm, emb.scatter_rows_cm)
+        if emb.column_major_default(v.shape, v.dtype, device)
+        else (emb.gather_rows, emb.scatter_rows)
+        for k, v in params.items() if k.startswith("emb/")
+    }
 
 
 def _round_spikes(durs: List[float]) -> List[int]:
@@ -594,6 +616,9 @@ class Graph4RecTrainer:
         elif cfg.sampling_backend not in ("host", "auto"):
             raise ValueError(f"unknown sampling_backend {cfg.sampling_backend!r}")
         self._grad_step = jax.jit(self._make_grad_step())
+        self._row_ops = table_row_ops(
+            jax.eval_shape(self.init_params), jax.devices()[0]
+        )
         # The sparse step additionally donates its (single-use, per-step)
         # device batch — the stager's H2D buffers are recycled into the
         # update outputs. The dense step must NOT donate batches: dense
@@ -715,6 +740,7 @@ class Graph4RecTrainer:
         mc = self.model_cfg
         cfg = self.cfg
         dense_opt = self._dense_opt
+        row_ops = self._row_ops
 
         def step(params, opt_state, batch):
             uniq = {f"emb/{k}": v for k, v in batch["uniq"].items()}
@@ -724,7 +750,7 @@ class Graph4RecTrainer:
             # Tables the batch never touches (e.g. slot tables with side info
             # disabled) pass straight through — no gather, no grads.
             touched = {k: v for k, v in sparse_p.items() if k in uniq}
-            sub = {k: emb.gather_rows(v, uniq[k]) for k, v in touched.items()}
+            sub = {k: row_ops[k][0](v, uniq[k]) for k, v in touched.items()}
 
             def loss_of(sub_tables, dense):
                 return model_lib.loss_fn({**dense, **sub_tables}, mc, model_batch)
@@ -737,6 +763,7 @@ class Graph4RecTrainer:
             new_touched, touched_state = emb_opt.rowwise_adagrad_scatter_update(
                 touched, g_sub, uniq, row_state,
                 lr=cfg.sparse_lr, eps=1e-8,
+                rows=sub, scatter={k: row_ops[k][1] for k in touched},
             )
             row_state = emb_opt.RowAdagradState(
                 accum={**row_state.accum, **touched_state.accum}
@@ -1088,6 +1115,11 @@ class Graph4RecTrainer:
             else None
         )
         use_fused = plan["sampling"] == "fused"
+        if self._sparse_on and not use_fused:
+            plan = {**plan, "table_rows": {
+                k: "row kernel" if g is emb.gather_rows_cm else "xla"
+                for k, (g, _) in self._row_ops.items()
+            }}
         if use_fused or self._sparse_on:
             # The fused and sparse steps donate their param buffers; copy
             # once so a caller-held pytree (e.g. for a later cold-start

@@ -241,3 +241,65 @@ class TestCostFlatInTableSize:
         t_large = time_step(100_000)
         # flat in N up to noise; a dense O(N) update would be ~10x
         assert t_large < t_small * 4 + 1e-4, (t_small, t_large)
+
+
+class TestRowKernelPath:
+    """Where the backend keeps a table column-major (a TPU), the sparse step
+    moves rows with the row kernels (kernels/table_rows.py); here, forced
+    on the CPU in interpret mode, the run must equal XLA's row ops bit for
+    bit, and its result must feed inference and checkpoints as before."""
+
+    @staticmethod
+    def _run(tr):
+        """train() and the sparse step's last optimizer state, which never
+        leaves train()."""
+        step, last = tr._sparse_step, []
+
+        def spy(params, opt_state, batch):
+            last[:] = [step(params, opt_state, batch)]
+            return last[0]
+
+        tr._sparse_step = spy
+        return tr.train(), last[0][1]
+
+    @pytest.fixture(scope="class")
+    def runs(self, ds):
+        from repro.embedding import table as emb_table
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(emb_table, "column_major_default", lambda *a: True)
+            kernel = build_trainer(ds, sparse=True, steps=5)
+        xla = build_trainer(ds, sparse=True, steps=5)
+        return (kernel, *self._run(kernel)), (xla, *self._run(xla))
+
+    def test_same_bits_as_xla_row_ops(self, runs):
+        (_, rk, (row_k, dense_k)), (_, rx, (row_x, dense_x)) = runs
+        assert rk.plan["table_rows"] == {"emb/node": "row kernel"}
+        assert rx.plan["table_rows"] == {"emb/node": "xla"}
+        np.testing.assert_array_equal(rk.losses, rx.losses)
+        for a, b in [(rk.params, rx.params), (row_k.accum, row_x.accum),
+                     (dense_k, dense_x)]:
+            la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+            assert len(la) == len(lb) > 0
+            for x, y in zip(la, lb):
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    def test_result_feeds_infer_and_checkpoint(self, runs, ds, tmp_path):
+        from repro.infer import embed_all_nodes
+        from repro.train import checkpoint
+
+        tr, res, _ = runs[0]
+        host = {k: np.asarray(v) for k, v in res.params.items()}
+        loaded = checkpoint.load_dict(
+            checkpoint.save(str(tmp_path / "params"), res.params))
+        assert loaded.keys() == host.keys()
+        for k in host:
+            np.testing.assert_array_equal(loaded[k], host[k])
+
+        def embed(params):
+            return embed_all_nodes(params, tr.model_cfg, tr.engine, ds.graph,
+                                   batch_size=256, seed=3)
+
+        want = embed(host)
+        np.testing.assert_array_equal(embed(res.params), want)
+        np.testing.assert_array_equal(embed(loaded), want)

@@ -21,6 +21,7 @@ import pytest
 from repro.kernels.inbatch_loss import inbatch_loss_rows_pallas
 from repro.kernels.ivf import dma_rows, ivf_list_topk_pallas
 from repro.kernels.seg_aggr import seg_aggr_pallas
+from repro.kernels.table_rows import gather_cols_pallas, scatter_cols_pallas
 from repro.kernels.topk import chunked_topk_pallas
 from repro.kernels.window_pairs import window_pair_ids_pallas
 from repro.sampling.pairs import window_positions
@@ -79,6 +80,15 @@ def _cases(d, spec):
             [spec((QUERIES, d), jnp.float32), spec((ITEMS, d), jnp.float32),
              spec((QUERIES, 16), jnp.int32)],
         ),
+        "table_gather": (  # the rows a sparse step pulls, from a table's transpose
+            gather_cols_pallas,
+            [spec((d, ITEMS), jnp.float32), spec((4096,), jnp.int32)],
+        ),
+        "table_scatter": (
+            scatter_cols_pallas,
+            [spec((d, ITEMS), jnp.float32), spec((4096,), jnp.int32),
+             spec((d, 4096), jnp.float32)],
+        ),
         "ivf": (
             functools.partial(ivf_list_topk_pallas, lpad=LPAD, shortlist=4 * K),
             [spec((QUERIES, d), jnp.float32), spec((ip, dc), jnp.int8),
@@ -90,7 +100,8 @@ def _cases(d, spec):
 
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize(
-    "name", ["seg_aggr", "window_pairs", "inbatch_loss", "topk", "ivf"]
+    "name", ["seg_aggr", "window_pairs", "inbatch_loss", "topk", "ivf",
+             "table_gather", "table_scatter"]
 )
 def test_kernel_compiles_for_v5e(one_chip, name, d):
     def spec(shape, dtype):
@@ -142,3 +153,54 @@ def test_ivf_kernel_is_what_tpu_selects():
         idx = IVFIndex.build(items, IVFConfig(nlist=8))
     assert idx._backend == "pallas"
     assert idx._dev["codes"].shape[1] % 128 == 0  # lane-padded for the DMA
+
+
+@pytest.mark.parametrize("rows", ["trainer", "xla"])
+def test_sparse_update_leaves_the_table_in_place_on_v5e(one_chip, rows,
+                                                        monkeypatch):
+    """The trainer's sparse update (row-wise AdaGrad; the table and its
+    accumulators donated) on an f32 (65,536, 64) table, which the chip keeps
+    column-major. With the row ops the trainer picks for that chip the
+    optimized program holds no table-sized copy; with XLA's row gather and
+    scatter, the control, XLA copies the whole table to row-major and back,
+    which shows this test can see such a copy."""
+    import re
+
+    from repro.embedding import gather_rows, scatter_rows
+    from repro.embedding import optimizer as emb_opt
+    from repro.kernels import ops
+    from repro.train.trainer import table_row_ops
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    n, d, bucket = 65_536, 64, 4096
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    table = spec((n, d), jnp.float32)
+    gather, scatter = (
+        table_row_ops({"emb/node": table},
+                      next(iter(one_chip.device_set)))["emb/node"]
+        if rows == "trainer" else (gather_rows, scatter_rows)
+    )
+    if rows == "trainer":
+        assert gather is not gather_rows  # the chip keeps the table by columns
+
+    def update(t, acc, ids, g):
+        new, state = emb_opt.rowwise_adagrad_scatter_update(
+            {"emb/node": t}, {"emb/node": g}, {"emb/node": ids},
+            emb_opt.RowAdagradState(accum={"emb/node": acc}), lr=0.2,
+            rows={"emb/node": gather(t, ids)}, scatter={"emb/node": scatter},
+        )
+        return new["emb/node"], state.accum["emb/node"]
+
+    hlo = jax.jit(update, donate_argnums=(0, 1)).lower(
+        table, spec((n, 1), jnp.float32), spec((bucket,), jnp.int32),
+        spec((bucket, d), jnp.float32),
+    ).compile().as_text()
+    copies = re.findall(rf"= f32\[({n},{d}|{d},{n})\]\{{[^}}]*\}} copy\(", hlo)
+    if rows == "trainer":
+        assert not copies, f"table-sized copies in the update: {copies}"
+        assert "tpu_custom_call" in hlo
+    else:
+        assert copies, "the control found no table-sized copy"
