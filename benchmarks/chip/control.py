@@ -58,23 +58,22 @@ def _training_seeds(ctx, st, seeds, control_seeds, emit):
 
     controls = _controls()
     tr = harness.load_module(HERE / "entries" / "train.py", "bench_train")
-    trainer, fused = st["trainer"], st["fused"]
+    trainer, fused, enc = st["trainer"], st["fused"], st["encoder"]
+    model, opt = ctx["config"]["model"], ctx["config"]["trainer"]
     for seed in seeds:
         s_trainer, s_weights = tr.seeds(seed)
         trainer.cfg.seed = s_trainer
-        p0 = tr.weights(ctx, trainer, s_weights, st["num_nodes"])
+        p0 = tr.weights(ctx, trainer, enc, s_weights, st["num_nodes"])
         first, prog, batches = tr.checked_steps(ctx, trainer, p0, fused)
         del first
-        emit(seed, "program", tr.reference_checks(ctx, p0, prog, batches,
-                                                  st["arrays"]))
+        emit(seed, "program", tr.reference_checks(
+            ctx, enc, p0, prog, batches, st["arrays"]))
         if seed in control_seeds:
             for kind, control in controls.items():
                 emit(seed, kind, tr.reference_checks(
-                    ctx, p0, prog, batches, st["arrays"], control))
-            want = ref.train_steps(p0, ctx["config"]["model"],
-                                   ctx["config"]["trainer"], batches)
-            half = ref.train_steps(p0, ctx["config"]["model"],
-                                   ctx["config"]["trainer"],
+                    ctx, enc, p0, prog, batches, st["arrays"], control))
+            want = ref.train_steps(p0, model, enc, opt, batches)
+            half = ref.train_steps(p0, model, enc, opt,
                                    [half_batch(b) for b in batches])
             emit(seed, "fault_half_batch", ref.compare_training(half, want))
         del p0

@@ -4,7 +4,9 @@ Only needed work is counted, never padding: an ego tower of a bipartite
 user-item graph has, at every hop, neighbours under exactly one of its
 relations (users have none under the item relation and the reverse), so
 the dense layout's other relation slots, and the children of those PAD
-slots, are padding. Backward is counted as twice the forward.
+slots, are padding. Backward is counted as twice the forward. An
+encoder's own work per parent and per edge comes from its modules, the
+``harness.encoder`` of its configuration.
 """
 from __future__ import annotations
 
@@ -26,21 +28,15 @@ def tower_edges(fanouts) -> Tuple[int, int]:
     return parents, edges
 
 
-def tower_flops(model: Dict) -> int:
-    """Forward FLOPs of the encoder for one ego tower."""
+def tower_flops(model: Dict, enc) -> int:
+    """Forward FLOPs of the encoder for one ego tower: what the encoder's
+    layer and mix modules count, plus Eq. 3's residual."""
     d = int(model["dim"])
     parents, edges = tower_edges([int(f) for f in model["fanouts"]])
-    mix = 3 * d  # alpha * h0 + (1 - alpha) * mixed
-    if model["gnn_type"] == "lightgcn":
-        per_parent, per_edge = d + mix, d  # one divide per dim; sums
-    elif model["gnn_type"] == "gat":
-        # W h_self and its score; per edge W h_nbr, its score, softmax and
-        # the weighted sum; relu on the parent
-        per_parent = 2 * d * d + 2 * d + d + mix
-        per_edge = 2 * d * d + 2 * d + 5 + 2 * d
-    else:
-        raise ValueError(f"no count for gnn_type {model['gnn_type']!r}")
-    return parents * per_parent + edges * per_edge
+    per_parent, per_edge = enc.layer.flops(d)
+    residual = 3 * d  # alpha * h0 + (1 - alpha) * mixed
+    return (parents * (per_parent + enc.mix.flops(d) + residual)
+            + edges * per_edge)
 
 
 def loss_flops(pairs: int, dim: int) -> int:
@@ -48,10 +44,11 @@ def loss_flops(pairs: int, dim: int) -> int:
     return 2 * pairs * pairs * dim + 4 * pairs * pairs
 
 
-def train_step_flops(model: Dict, pairs: int, towers: int) -> int:
+def train_step_flops(model: Dict, enc, pairs: int, towers: int) -> int:
     """Forward plus backward FLOPs of one step that encodes ``towers`` ego
     trees and scores ``pairs`` pairs."""
-    fwd = towers * tower_flops(model) + loss_flops(pairs, int(model["dim"]))
+    fwd = (towers * tower_flops(model, enc)
+           + loss_flops(pairs, int(model["dim"])))
     return 3 * fwd
 
 

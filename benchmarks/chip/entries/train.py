@@ -215,13 +215,14 @@ def global_batch(fed, sampler) -> Dict:
 
 
 def build(ctx: Dict, seed_trainer: int) -> Dict:
-    """Set-up up to a trainer ready for its first step: the graph (cached
-    or generated), the engine, the program's configurations, the trainer
-    with every setting pinned."""
+    """Set-up up to a trainer ready for its first step: the encoder's
+    reference modules, the graph (cached or generated), the engine, the
+    program's configurations, the trainer with every setting pinned."""
     from repro.graph import DistributedGraphEngine
     from repro.train import Graph4RecTrainer
 
     cfg, traffic = ctx["config"], ctx["traffic"]
+    enc = harness.encoder(cfg["model"], HERE)  # before the graph: fail fast
     ub = harness.load_module(HERE / "data" / "ub.py", "bench_ub")
     arrays, meta = ub.load_or_generate(cfg["deployment"], ctx["cache"],
                                        ctx["log"])
@@ -236,14 +237,15 @@ def build(ctx: Dict, seed_trainer: int) -> Dict:
     if fused and trainer._fused_sampler is None:
         raise harness.RunError("the fused sampler was not admitted")
     return {"arrays": arrays, "meta": meta, "num_nodes": graph.num_nodes,
-            "trainer": trainer, "pipe_cfg": pipe_cfg, "fused": fused}
+            "trainer": trainer, "pipe_cfg": pipe_cfg, "fused": fused,
+            "encoder": enc}
 
 
-def weights(ctx: Dict, trainer, seed_weights: int, num_nodes: int):
+def weights(ctx: Dict, trainer, enc, seed_weights: int, num_nodes: int):
     """The benchmark's weights, checked against the program's layout."""
     import jax
 
-    p0 = weights_only(ctx, seed_weights, num_nodes)
+    p0 = weights_only(ctx, enc, seed_weights, num_nodes)
     want = jax.eval_shape(trainer.init_params)
     got = jax.eval_shape(lambda: p0)
     if jax.tree.structure(want) != jax.tree.structure(got) or any(
@@ -280,7 +282,7 @@ def checked_steps(ctx: Dict, trainer, p0, fused: bool):
     return first, prog, batches
 
 
-def reference_checks(ctx: Dict, p0, prog: Dict, batches, arrays,
+def reference_checks(ctx: Dict, enc, p0, prog: Dict, batches, arrays,
                      control=None) -> Dict:
     """The compared numbers: the program's readings against the reference
     run on the same batches from ``p0``. With ``control`` (a dtype and a
@@ -290,9 +292,10 @@ def reference_checks(ctx: Dict, p0, prog: Dict, batches, arrays,
 
     cfg = ctx["config"]
     model = cfg["model"]
-    want = ref.train_steps(p0, model, cfg["trainer"], batches)
+    want = ref.train_steps(p0, model, enc, cfg["trainer"], batches)
     if control is not None:
-        prog = ref.train_steps(p0, model, cfg["trainer"], batches, *control)
+        prog = ref.train_steps(p0, model, enc, cfg["trainer"], batches,
+                               *control)
     checks = ref.compare_training(prog, want)
     csr = {r: (arrays[f"{r}.indptr"], arrays[f"{r}.indices"])
            for r in {k.rsplit(".", 1)[0] for k in arrays}}
@@ -340,7 +343,7 @@ def _run(ctx: Dict, st: Dict, seed_trainer: int, seed_weights: int) -> Dict:
     traffic, log, clock = ctx["traffic"], ctx["log"], ctx["clock"]
     model = ctx["config"]["model"]
     trainer, pipe_cfg, fused = st["trainer"], st["pipe_cfg"], st["fused"]
-    p0 = weights(ctx, trainer, seed_weights, st["num_nodes"])
+    p0 = weights(ctx, trainer, st["encoder"], seed_weights, st["num_nodes"])
     first, prog, batches = checked_steps(ctx, trainer, p0, fused)
     sampler = trainer._fused_sampler if fused else None
     del p0
@@ -401,6 +404,7 @@ def _run(ctx: Dict, st: Dict, seed_trainer: int, seed_weights: int) -> Dict:
             "phases": res.attribution, "steps": steps, "wall_s": wall,
             "pairs_per_s": pairs_per_s,
             "fused": fused, "peak": ctx["peak"], "model": model,
+            "encoder": st["encoder"],
             "pairs": pipe_cfg.batch_pairs,
             "towers": (sampler.num_walks * pipe_cfg.walk.walk_len
                        if fused else 2 * pipe_cfg.batch_pairs),
@@ -416,8 +420,9 @@ def _run(ctx: Dict, st: Dict, seed_trainer: int, seed_weights: int) -> Dict:
     del trainer, res, warm, first, params, sampler
     st["trainer"] = None
     gc.collect()
-    p0 = weights_only(ctx, seed_weights, st["num_nodes"])
-    checks = reference_checks(ctx, p0, prog, batches, st["arrays"])
+    p0 = weights_only(ctx, st["encoder"], seed_weights, st["num_nodes"])
+    checks = reference_checks(ctx, st["encoder"], p0, prog, batches,
+                              st["arrays"])
     return {
         "e2e": {"pairs_per_s": pairs_per_s, "setup_s": setup_s},
         "checks": checks, "attempted": steps, "failed": failed,
@@ -425,10 +430,10 @@ def _run(ctx: Dict, st: Dict, seed_trainer: int, seed_weights: int) -> Dict:
     }
 
 
-def weights_only(ctx: Dict, seed_weights: int, num_nodes: int):
+def weights_only(ctx: Dict, enc, seed_weights: int, num_nodes: int):
     import jax
 
     import reference as ref
 
     return ref.make_params(jax.random.PRNGKey(seed_weights),
-                           ctx["config"]["model"], num_nodes)
+                           ctx["config"]["model"], enc, num_nodes)

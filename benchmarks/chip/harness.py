@@ -10,6 +10,19 @@ harness finds them by name, with no list of its own:
   returns a number or None where the run has nothing to read;
 - ``limits/<workload>.json``: the limits of the cell's correctness numbers.
 
+A training configuration's encoder is found the same way, by the
+program's own two axes of it (``HeteroGNNConfig.gnn_type`` and
+``relation_agg``): ``encoders/<model.gnn_type>.py`` is the per-relation
+layer and ``mixes/<model.relation_agg>.py`` the relation mix of Eq. 3.
+Each gives ``leaves(d)``, its leaves named as the program names them with
+their shapes and initial scales at width d; ``forward``, in plain float32
+``jax.numpy`` with nothing from the program; and ``flops(d)``, the needed
+forward FLOPs, per parent and per child edge for a layer and per parent
+for a mix. What every encoder shares stays in ``reference.py`` and
+``counts.py``. The training entry loads the two (``encoder``) first,
+before the graph is loaded or generated, so a configuration the
+reference cannot check ends the run in seconds.
+
 An entry's ``run(ctx)`` returns the end-to-end numbers, the correctness
 numbers and whatever its per-layer readers consume; this module turns
 that into the result line.
@@ -23,7 +36,8 @@ import os
 import sys
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Dict, List, NamedTuple, Optional
 
 HERE = Path(__file__).resolve().parent
 CACHE = HERE / ".cache"
@@ -47,6 +61,24 @@ def load_module(path: Path, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+class Encoder(NamedTuple):
+    layer: ModuleType
+    mix: ModuleType
+
+
+def encoder(model: Dict, here: Path) -> Encoder:
+    """The modules of a training configuration's encoder under ``here``.
+    Without ``relation_agg`` the mix is the program's default, uniform."""
+    if model.get("side_info"):
+        raise RunError("the reference does not model side_info: true")
+    gnn, agg = model["gnn_type"], model.get("relation_agg", "uniform")
+    return Encoder(
+        load_module(here / "encoders" / f"{gnn}.py",
+                    "bench_encoder_" + gnn.replace("-", "_")),
+        load_module(here / "mixes" / f"{agg}.py",
+                    "bench_mix_" + agg.replace("-", "_")))
 
 
 def resolve(bench: Dict, root: Path, workload: str) -> Dict:

@@ -2,15 +2,17 @@
 
 Straightforward ``jax.numpy`` with no kernels, no sparse tricks and
 nothing imported from the program: the relation-wise GNN of Graph4Rec
-Eq. 3 (LightGCN's masked mean or GAT's attention over each relation's
-sampled neighbours, a uniform mix over relations and an ``alpha`` residual
-to the hop-0 embedding), the in-batch softmax loss, and the optimizer the
-configuration states (row-wise AdaGrad on the embedding table, Adam on the
-dense weights), applied to the full table. ``dtype`` float32 runs under
-``highest`` matmul precision, float32 as the configurations state it. The
-controls are the same computation one step below: float32 at ``high``
-(three bfloat16 passes) or ``default`` (one pass, JAX's own on a TPU), and
-bfloat16 throughout.
+Eq. 3 (a per-relation layer over each relation's sampled neighbours, a
+mix over relations and an ``alpha`` residual to the hop-0 embedding), the
+in-batch softmax loss, and the optimizer the configuration states
+(row-wise AdaGrad on the embedding table, Adam on the dense weights),
+applied to the full table. The layer and the mix are the configuration's
+encoder modules (``harness.encoder``: ``encoders/<gnn_type>.py`` and
+``mixes/<relation_agg>.py``); everything else here is shared by every
+encoder. ``dtype`` float32 runs under ``highest`` matmul precision,
+float32 as the configurations state it. The controls are the same
+computation one step below: float32 at ``high`` (three bfloat16 passes)
+or ``default`` (one pass, JAX's own on a TPU), and bfloat16 throughout.
 
 Also here: the benchmark's own weight initialisation (one jitted call from
 the seed, made on the device), the check that a sampled batch lies in the
@@ -19,7 +21,7 @@ graph, and exact top-k retrieval in item blocks.
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,38 +31,35 @@ ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
 
 
 # ----------------------------------------------------------------- weights
-def param_shapes(model: Dict, num_nodes: int) -> Dict[str, Tuple[int, ...]]:
+def param_specs(model: Dict, enc, num_nodes: int
+                 ) -> Dict[str, Tuple[Tuple[int, ...], Optional[float]]]:
+    """Every leaf, named as the program names it, with its shape and the
+    scale of its N(0, 1) draw: the table (scaled in ``make_params``), the
+    layer's leaves for each layer and relation, and the mix's leaves."""
     d = int(model["dim"])
-    shapes = {"emb/node": (num_nodes, d)}
-    if model["gnn_type"] == "gat":
-        for layer in range(int(model["num_layers"])):
-            for r in range(len(model["relations"])):
-                pre = f"gnn/l{layer}/r{r}/"
-                shapes[pre + "w"] = (d, d)
-                shapes[pre + "a_self"] = (d,)
-                shapes[pre + "a_nbr"] = (d,)
-    elif model["gnn_type"] != "lightgcn":
-        raise ValueError(f"no reference for gnn_type {model['gnn_type']!r}")
-    return shapes
+    out = {"emb/node": ((num_nodes, d), None)}
+    for layer in range(int(model["num_layers"])):
+        for r in range(len(model["relations"])):
+            for name, spec in enc.layer.leaves(d).items():
+                out[f"gnn/l{layer}/r{r}/{name}"] = spec
+    for name, spec in enc.mix.leaves(d).items():
+        out[f"gnn/{name}"] = spec
+    return out
 
 
-def make_params(key: jax.Array, model: Dict, num_nodes: int) -> Dict:
-    """Weights from the seed, on the device, in one jitted call: the table
-    N(0, 1/d) per entry, Glorot-normal ``w``, N(0, 0.01) attention vectors."""
-    shapes = param_shapes(model, num_nodes)
+def make_params(key: jax.Array, model: Dict, enc, num_nodes: int) -> Dict:
+    """Weights from the seed, on the device, in one jitted call: one key
+    per leaf in the order of the leaves' names, the table N(0, 1/d) per
+    entry, every other leaf at its module's scale."""
+    leaves = param_specs(model, enc, num_nodes)
     d = int(model["dim"])
 
     def init(key):
         out = {}
-        keys = jax.random.split(key, len(shapes))
-        for k, (name, shape) in zip(keys, sorted(shapes.items())):
+        keys = jax.random.split(key, len(leaves))
+        for k, (name, (shape, scale)) in zip(keys, sorted(leaves.items())):
             z = jax.random.normal(k, shape, jnp.float32)
-            if name == "emb/node":
-                out[name] = z / np.sqrt(d)
-            elif name.endswith("/w"):
-                out[name] = z * np.sqrt(2.0 / (2 * d))
-            else:
-                out[name] = z * 0.1
+            out[name] = z / np.sqrt(d) if scale is None else z * scale
         return out
 
     return jax.jit(init)(key)
@@ -72,51 +71,39 @@ def _embed(table, ids):
     return jnp.where((ids >= 0)[..., None], rows, jnp.zeros((), table.dtype))
 
 
-def _relation_layer(params, model, layer, r, h_self, h_nbr, mask):
-    m = mask[..., None].astype(h_nbr.dtype)
-    if model["gnn_type"] == "lightgcn":
-        return (h_nbr * m).sum(-2) / jnp.maximum(m.sum(-2), 1)
-    pre = f"gnn/l{layer}/r{r}/"
-    w = params[pre + "w"]
-    ws, wn = h_self @ w, h_nbr @ w
-    e = (ws * params[pre + "a_self"]).sum(-1)[..., None] + (
-        wn * params[pre + "a_nbr"]).sum(-1)
-    e = jnp.where(e >= 0, e, 0.2 * e)
-    e = jnp.where(mask, e, jnp.asarray(-1e9, e.dtype))
-    att = jnp.exp(e - e.max(-1, keepdims=True))
-    att = att / att.sum(-1, keepdims=True)
-    att = jnp.where(mask, att, jnp.zeros((), att.dtype))
-    return jnp.maximum((att[..., None] * wn).sum(-2), 0)
-
-
-def encode(params, model: Dict, levels: Sequence[jnp.ndarray]) -> jnp.ndarray:
+def encode(params, model: Dict, enc, levels: Sequence[jnp.ndarray]
+           ) -> jnp.ndarray:
     """Towers (level k: (B, W_k) global ids, PAD -1) -> (B, d)."""
     fan = [int(f) for f in model["fanouts"]]
     R = len(model["relations"])
     alpha = float(model["alpha"])
+    d = int(model["dim"])
+    mix = {n: params["gnn/" + n] for n in enc.mix.leaves(d)}
     h = [_embed(params["emb/node"], l) for l in levels]
     h0, masks = list(h), [l >= 0 for l in levels]
     for layer in range(len(fan)):
+        per_rel = [{n: params[f"gnn/l{layer}/r{r}/{n}"]
+                    for n in enc.layer.leaves(d)} for r in range(R)]
         nxt = []
         for k in range(len(fan) - layer):
-            B, W, d = h[k].shape
+            B, W, _ = h[k].shape
             child = h[k + 1].reshape(B, W, R, fan[k], d)
             cmask = masks[k + 1].reshape(B, W, R, fan[k])
             rel = [
-                _relation_layer(params, model, layer, r, h[k], child[:, :, r],
-                                cmask[:, :, r])
+                enc.layer.forward(per_rel[r], h[k], child[:, :, r],
+                                  cmask[:, :, r])
                 for r in range(R)
             ]
-            mixed = sum(rel) / R
+            mixed = enc.mix.forward(mix, rel)
             out = alpha * h0[k] + (1 - alpha) * mixed
             nxt.append(out * masks[k][..., None].astype(out.dtype))
         h = nxt
     return h[0][:, 0, :]
 
 
-def loss(params, model: Dict, batch: Dict) -> jnp.ndarray:
+def loss(params, model: Dict, enc, batch: Dict) -> jnp.ndarray:
     """In-batch softmax over the pairs (src_sel[p], dst_sel[p]) of towers."""
-    h = encode(params, model, batch["towers"])
+    h = encode(params, model, enc, batch["towers"])
     hs, hd = h[batch["src_sel"]], h[batch["dst_sel"]]
     logits = hs @ hd.T / float(model.get("temperature", 1.0))
     mx = logits.max(-1, keepdims=True)
@@ -130,8 +117,9 @@ def _precision(dtype, precision="highest"):
     return contextlib.nullcontext()
 
 
-def train_steps(params0: Dict, model: Dict, opt: Dict, batches: List[Dict],
-                dtype=jnp.float32, precision: str = "highest") -> Dict:
+def train_steps(params0: Dict, model: Dict, enc, opt: Dict,
+                batches: List[Dict], dtype=jnp.float32,
+                precision: str = "highest") -> Dict:
     """The configuration's training steps over ``batches`` from
     ``params0``. Returns per-step losses, the first gradient's norm per
     leaf, and the norm of each leaf's change after the last step. float32
@@ -140,7 +128,7 @@ def train_steps(params0: Dict, model: Dict, opt: Dict, batches: List[Dict],
     acc0, eps = float(opt["adagrad_init_accum"]), 1e-8
 
     def step(p, acc, mu, nu, t, batch):
-        lv, g = jax.value_and_grad(loss)(p, model, batch)
+        lv, g = jax.value_and_grad(loss)(p, model, enc, batch)
         new = {}
         for k, v in p.items():
             if k.startswith("emb/"):

@@ -32,6 +32,7 @@ def _fails(readings, limits):
 
 
 @pytest.mark.parametrize("workload", ["lightgcn-ub.host", "gat-ub.fused",
+                                      "lightgcn-ub.fused",
                                       "lightgcn-ub.retrieve"])
 def test_control_fails_program_passes(tiny_root, workload):
     rows, limits = _readings(tiny_root, workload, [11, 12], {11})

@@ -21,11 +21,13 @@ def test_tower_flops_by_hand():
     # d = 2, fanouts (4, 3): 6 parents, 20 edges
     lightgcn = {"gnn_type": "lightgcn", "dim": 2, "fanouts": [4, 3]}
     # per parent: d divides + 3d for the residual mix; per edge: d adds
-    assert counts.tower_flops(lightgcn) == 6 * (2 + 6) + 20 * 2
+    assert counts.tower_flops(lightgcn, harness.encoder(lightgcn, CHIP)) == (
+        6 * (2 + 6) + 20 * 2)
     gat = {"gnn_type": "gat", "dim": 2, "fanouts": [4, 3]}
     # per parent: 2d^2 (W h) + 2d (score) + d (relu) + 3d (mix) = 20
     # per edge: 2d^2 (W h) + 2d (score) + 5 (softmax) + 2d (sum) = 21
-    assert counts.tower_flops(gat) == 6 * 20 + 20 * 21
+    assert counts.tower_flops(gat, harness.encoder(gat, CHIP)) == (
+        6 * 20 + 20 * 21)
 
 
 def test_step_and_loss_flops_by_hand():
@@ -33,7 +35,8 @@ def test_step_and_loss_flops_by_hand():
     model = {"gnn_type": "lightgcn", "dim": 3, "fanouts": [1]}
     # one tower: 1 parent (3 + 9) + 1 edge (3) = 15; 4 towers, 2 pairs
     fwd = 4 * 15 + counts.loss_flops(2, 3)
-    assert counts.train_step_flops(model, 2, 4) == 3 * fwd
+    enc = harness.encoder(model, CHIP)
+    assert counts.train_step_flops(model, enc, 2, 4) == 3 * fwd
 
 
 def test_kernel_costs_by_hand():

@@ -24,6 +24,7 @@ def _unchanged_state(factory):
 @pytest.mark.parametrize("workload,factory", [
     ("lightgcn-ub.host", "_make_sparse_step"),
     ("gat-ub.fused", "_make_fused_step"),
+    ("lightgcn-ub.fused", "_make_fused_step"),
 ])
 def test_step_returns_state_unchanged(tiny_root, monkeypatch, workload,
                                       factory):

@@ -1,14 +1,22 @@
 """The harness finds a cell's files by name: a new configuration, mix,
-entry, metric and limits file run with no edit to any existing file. And
-a run that finds no chip, or no program, prints no result."""
+entry, metric and limits file run with no edit to any existing file, and
+so does a new encoder of the program's zoo, whose reference and count are
+files of their own. A configuration the reference does not model ends the
+run before its graph is made. And a run that finds no chip, or no program,
+prints no result."""
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
 from pathlib import Path
 
+import pytest
+
 import conftest
+import counts
+import harness
 
 
 def _add_dummy(root: Path) -> None:
@@ -65,6 +73,112 @@ def test_added_files_run_with_no_edit(tmp_path):
     assert traced["metrics"] == {"dummy.x": {"value": 2.5, "unit": "1"}}
     assert traced["device"]["busy_s"] == 1.0
     assert traced["device"]["window_s"] == 2.0
+
+
+SAGE_MEAN = """\
+import jax.numpy as jnp
+import numpy as np
+
+
+def leaves(d):
+    return {"w": ((2 * d, d), np.sqrt(2.0 / (3 * d)))}
+
+
+def forward(p, h_self, h_nbr, mask):
+    m = mask[..., None].astype(h_nbr.dtype)
+    agg = (h_nbr * m).sum(-2) / jnp.maximum(m.sum(-2), 1)
+    return jnp.maximum(jnp.concatenate([h_self, agg], -1) @ p["w"], 0)
+
+
+def flops(d):
+    return 2 * (2 * d) * d + 2 * d, d
+"""
+
+
+def _add_sage_mean(root: Path) -> None:
+    """GraphSAGE-mean as core/gnn.py computes it, relu([h_self, masked
+    mean] @ w) with w of shape (2d, d), in a cell on the host mix."""
+    chip = root / "benchmarks" / "chip"
+    (chip / "encoders" / "sage-mean.py").write_text(SAGE_MEAN)
+    cfg = json.loads((chip / "configs" / "lightgcn-ub.json").read_text())
+    cfg["name"] = "sage-ub"
+    cfg["model"]["gnn_type"] = "sage-mean"
+    (chip / "configs" / "sage-ub.json").write_text(json.dumps(cfg))
+    (chip / "limits" / "sage-ub.host.json").write_text(
+        (chip / "limits" / "lightgcn-ub.host.json").read_text())
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "sage-ub", "source": "test",
+                             "file": "benchmarks/chip/configs/sage-ub.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "sage-ub.host", "config": "sage-ub",
+                               "traffic": "host", "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("pairs_per_s", "train.mfu"):
+            m["workloads"].append("sage-ub.host")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+class _NamedChip:
+    """A CPU device that reports itself as a v5e, so that a traced run on
+    the CPU takes that chip's peaks."""
+
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def memory_stats(self):
+        return {}
+
+
+def test_zoo_encoder_enters_as_files(tmp_path, monkeypatch):
+    root = conftest.make_root(tmp_path)
+    chip = root / "benchmarks" / "chip"
+    before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
+    _add_sage_mean(root)
+    for p, data in before.items():
+        assert p.read_bytes() == data, f"{p} was edited"
+    out = conftest.run_cell(root, "sage-ub.host")
+    assert out["correct"] is True, out["checks"]
+    assert set(out["metrics"]) == {"pairs_per_s", "setup_s"}
+    # d = 64, fanouts (4, 3): 6 parents, 20 edges; per parent 4d^2 + 2d
+    # and the residual's 3d, per edge d
+    cfg = json.loads((chip / "configs" / "sage-ub.json").read_text())
+    enc = harness.encoder(cfg["model"], chip)
+    assert counts.tower_flops(cfg["model"], enc) == (
+        6 * (4 * 64 * 64 + 2 * 64 + 3 * 64) + 20 * 64)
+    # the CPU's trace has no device plane: the traced run reads one made up
+    monkeypatch.setattr(harness.Traced, "reduce", lambda self, **kw: {
+        "busy_s": 1.0, "window_s": 2.0, "modules": {}, "ops": {},
+        "top_ops": [], "idle": []})
+    traced = harness.run(
+        ["--workload", "sage-ub.host", "--seed", "7", "--seconds", "0.5",
+         "--trace", "1"], 0.0, root, cache=chip / ".cache",
+        chip_check=lambda n: [_NamedChip()])
+    assert traced["correct"] is True
+    assert traced["metrics"]["train.mfu"]["value"] > 0
+
+
+@pytest.mark.parametrize("change,named", [
+    ({"relation_agg": "gatne"}, "mixes/gatne.py"),
+    ({"side_info": True}, "side_info"),
+])
+def test_unmodelled_encoder_ends_before_the_graph(tmp_path, monkeypatch,
+                                                  change, named):
+    root = conftest.make_root(tmp_path)
+    f = root / "benchmarks" / "chip" / "configs" / "lightgcn-ub.json"
+    cfg = json.loads(f.read_text())
+    cfg["model"].update(change)
+    f.write_text(json.dumps(cfg))
+    loaded = []
+    load = harness.load_module
+
+    def spy(path, name):
+        loaded.append(Path(path).name)
+        return load(path, name)
+
+    monkeypatch.setattr(harness, "load_module", spy)
+    with pytest.raises(SystemExit, match=re.escape(named)):
+        conftest.run_cell(root, "lightgcn-ub.host")
+    assert "ub.py" not in loaded
 
 
 def _bare_run(root: Path, workload: str):
