@@ -1,42 +1,65 @@
 """Operations and bytes the algorithms need, computed from shapes.
 
-Only needed work is counted, never padding: an ego tower of a bipartite
-user-item graph has, at every hop, neighbours under exactly one of its
-relations (users have none under the item relation and the reverse), so
-the dense layout's other relation slots, and the children of those PAD
-slots, are padding. Backward is counted as twice the forward. An
-encoder's own work per parent and per edge comes from its modules, the
-``harness.encoder`` of its configuration.
+Only needed work is counted, never padding. An encoder's work is counted
+on the ego towers a run really encoded, in the reference's layout: a
+parent slot holds one block of ``fanouts[k]`` child slots per relation,
+and which of those are live depends on the graph (a user has no
+neighbours under an item relation; a node with no ``buy`` edge gets an
+all-PAD ``buy`` block). PAD slots, and relation blocks with no live
+child, are padding. Backward is counted as twice the forward. An
+encoder's own work per (parent, relation) pair, per edge and per mix
+comes from its modules, the ``harness.encoder`` of its configuration.
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from collections import Counter
+from typing import Dict, Sequence, Tuple
+
+import numpy as np
 
 
-def tower_edges(fanouts) -> Tuple[int, int]:
-    """(parent aggregations, child edges) over all layers of one needed
-    ego tree: layer l collapses levels 0..K-1-l into their parents."""
+def tower_work(levels: Sequence[np.ndarray], fanouts: Sequence[int],
+               relations: int) -> Tuple[int, int, Dict[int, int]]:
+    """Needed work of the ego towers ``levels`` (level k: (B, W_k) ids,
+    PAD -1; level k + 1 holds ``relations`` blocks of ``fanouts[k]``
+    children per level-k slot) over all layers: layer l collapses levels
+    0..K-1-l into their parents, so level k is a parent in K - k layers.
+
+    Returns (live (parent, relation) pairs, live child edges, live parents
+    by the number of live relations they mix). A pair is live where the
+    parent is live and has at least one live child under the relation.
+    """
+    live = [np.asarray(l) >= 0 for l in levels]
     K = len(fanouts)
-    width = [1]
-    for f in fanouts:
-        width.append(width[-1] * f)
-    parents = edges = 0
-    for layer in range(K):
-        for k in range(K - layer):
-            parents += width[k]
-            edges += width[k] * fanouts[k]
-    return parents, edges
+    pairs = edges = 0
+    mixes: Counter = Counter()
+    for k, f in enumerate(fanouts):
+        B, W = live[k].shape
+        child = (live[k + 1].reshape(B, W, relations, f)
+                 & live[k][..., None, None])
+        per_rel = child.sum(-1)  # live children of each (parent, relation)
+        n = (per_rel > 0).sum(-1)[live[k]]  # live relations of live parents
+        pairs += (K - k) * int(n.sum())
+        edges += (K - k) * int(per_rel.sum())
+        for v, c in zip(*np.unique(n, return_counts=True)):
+            mixes[int(v)] += (K - k) * int(c)
+    return pairs, edges, dict(mixes)
 
 
-def tower_flops(model: Dict, enc) -> int:
-    """Forward FLOPs of the encoder for one ego tower: what the encoder's
-    layer and mix modules count, plus Eq. 3's residual."""
+def tower_flops(model: Dict, enc, levels: Sequence[np.ndarray]) -> int:
+    """Needed forward FLOPs of the encoder over the ego towers ``levels``
+    (all B of them): the layer module's work per live (parent, relation)
+    pair and per live edge, the mix module's over each live parent's live
+    relations, and Eq. 3's residual per live parent (alone where the
+    parent has no live relation)."""
     d = int(model["dim"])
-    parents, edges = tower_edges([int(f) for f in model["fanouts"]])
-    per_parent, per_edge = enc.layer.flops(d)
+    pairs, edges, mixes = tower_work(
+        levels, [int(f) for f in model["fanouts"]], len(model["relations"]))
+    per_pair, per_edge = enc.layer.flops(d)
     residual = 3 * d  # alpha * h0 + (1 - alpha) * mixed
-    return (parents * (per_parent + enc.mix.flops(d) + residual)
-            + edges * per_edge)
+    return (pairs * per_pair + edges * per_edge
+            + sum(c * (residual + (enc.mix.flops(d, n) if n else 0))
+                  for n, c in mixes.items()))
 
 
 def loss_flops(pairs: int, dim: int) -> int:
@@ -44,12 +67,13 @@ def loss_flops(pairs: int, dim: int) -> int:
     return 2 * pairs * pairs * dim + 4 * pairs * pairs
 
 
-def train_step_flops(model: Dict, enc, pairs: int, towers: int) -> int:
-    """Forward plus backward FLOPs of one step that encodes ``towers`` ego
-    trees and scores ``pairs`` pairs."""
-    fwd = (towers * tower_flops(model, enc)
-           + loss_flops(pairs, int(model["dim"])))
-    return 3 * fwd
+def train_step_flops(model: Dict, enc, pairs: int, steps) -> float:
+    """Forward plus backward FLOPs of one step that scores ``pairs``
+    pairs: the encoder's needed work on the towers of the checked
+    ``steps`` (each a list of levels, a row per tower the step encodes),
+    averaged over them, and the loss."""
+    fwd = np.mean([tower_flops(model, enc, levels) for levels in steps])
+    return 3 * (float(fwd) + loss_flops(pairs, int(model["dim"])))
 
 
 def window_pairs_cost(walks: int, walk_len: int, npos: int) -> Tuple[int, int]:

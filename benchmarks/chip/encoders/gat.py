@@ -25,7 +25,7 @@ def forward(p, h_self, h_nbr, mask):
 
 
 def flops(d):
-    """(per parent, per child edge): on the parent ``w h_self``, its score
-    and the ReLU; per edge ``w h_nbr``, its score, the softmax (5) and the
-    weighted sum."""
+    """(per live (parent, relation) pair, per live child edge): on the pair
+    the relation's ``w h_self``, its score and the ReLU; per edge
+    ``w h_nbr``, its score, the softmax (5) and the weighted sum."""
     return 2 * d * d + 2 * d + d, 2 * d * d + 2 * d + 5 + 2 * d

@@ -14,5 +14,6 @@ def forward(p, h_self, h_nbr, mask):
 
 
 def flops(d):
-    """(per parent, per child edge): one divide per dim; one add per dim."""
+    """(per live (parent, relation) pair, per live child edge): one divide
+    per dim; one add per dim."""
     return d, d

@@ -406,8 +406,7 @@ def _run(ctx: Dict, st: Dict, seed_trainer: int, seed_weights: int) -> Dict:
             "fused": fused, "peak": ctx["peak"], "model": model,
             "encoder": st["encoder"],
             "pairs": pipe_cfg.batch_pairs,
-            "towers": (sampler.num_walks * pipe_cfg.walk.walk_len
-                       if fused else 2 * pipe_cfg.batch_pairs),
+            "checked_towers": [b["towers"] for b in batches],
             "walks": sampler.num_walks if fused else None,
             "walk_len": pipe_cfg.walk.walk_len,
             "npos": len(sampler._positions) if fused else None,

@@ -16,10 +16,12 @@ program's own two axes of it (``HeteroGNNConfig.gnn_type`` and
 layer and ``mixes/<model.relation_agg>.py`` the relation mix of Eq. 3.
 Each gives ``leaves(d)``, its leaves named as the program names them with
 their shapes and initial scales at width d; ``forward``, in plain float32
-``jax.numpy`` with nothing from the program; and ``flops(d)``, the needed
-forward FLOPs, per parent and per child edge for a layer and per parent
-for a mix. What every encoder shares stays in ``reference.py`` and
-``counts.py``. The training entry loads the two (``encoder``) first,
+``jax.numpy`` with nothing from the program; and ``flops``, the needed
+forward FLOPs: a layer's ``flops(d)`` per live (parent, relation) pair
+and per live child edge, a mix's ``flops(d, n)`` per live parent over
+its n live relations. What every encoder shares stays in
+``reference.py`` and ``counts.py``, which counts on the towers a run
+encoded. The training entry loads the two (``encoder``) first,
 before the graph is loaded or generated, so a configuration the
 reference cannot check ends the run in seconds.
 

@@ -10,7 +10,8 @@ def forward(p, rel):
     return sum(rel) / len(rel)
 
 
-def flops(d):
-    """Per parent: none needed. A bipartite graph leaves one relation live
-    per ego node, and its 1/R folds into the residual's (1 - alpha)."""
-    return 0
+def flops(d, n):
+    """Per live parent mixing ``n`` >= 1 live relations: the n - 1 adds of
+    their sum (a dead relation's output is 0); the 1/R folds into the
+    residual's (1 - alpha)."""
+    return (n - 1) * d

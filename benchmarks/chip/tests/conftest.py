@@ -13,6 +13,7 @@ import sys
 import time
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 CHIP = Path(__file__).resolve().parents[1]
@@ -66,3 +67,14 @@ def run_cell(root: Path, workload: str, seed: int = 7, seconds: float = 0.5,
          str(seconds), "--trace", str(trace)],
         time.perf_counter(), root, cache=root / "benchmarks/chip/.cache",
         chip_check=cpu_devices)
+
+
+def full_tower(fanouts, relations=1, live=0):
+    """One ego tower in the reference's layout: every live slot has all
+    its children under relation ``live`` and none under the others."""
+    levels = [np.zeros((1, 1), np.int64)]
+    for f in fanouts:
+        block = np.full((levels[-1].shape[1], relations, f), -1, np.int64)
+        block[levels[-1][0] >= 0, live] = 1
+        levels.append(block.reshape(1, -1))
+    return levels

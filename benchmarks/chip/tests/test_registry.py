@@ -1,9 +1,10 @@
 """The harness finds a cell's files by name: a new configuration, mix,
 entry, metric and limits file run with no edit to any existing file, and
 so does a new encoder of the program's zoo, whose reference and count are
-files of their own. A configuration the reference does not model ends the
-run before its graph is made. And a run that finds no chip, or no program,
-prints no result."""
+files of their own, and a configuration over all eight UB relations,
+whose count follows the relations its towers hold. A configuration the
+reference does not model ends the run before its graph is made. And a
+run that finds no chip, or no program, prints no result."""
 import json
 import os
 import re
@@ -12,11 +13,13 @@ import subprocess
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 import conftest
 import counts
 import harness
+import reference
 
 
 def _add_dummy(root: Path) -> None:
@@ -95,28 +98,38 @@ def flops(d):
 """
 
 
-def _add_sage_mean(root: Path) -> None:
-    """GraphSAGE-mean as core/gnn.py computes it, relu([h_self, masked
-    mean] @ w) with w of shape (2d, d), in a cell on the host mix."""
+def _add_host_cell(root: Path, name: str, **model) -> str:
+    """Configuration ``name``, lightgcn-ub's with ``model``'s keys changed,
+    in a cell on the host mix with lightgcn-ub.host's limits; the cell's
+    name."""
     chip = root / "benchmarks" / "chip"
-    (chip / "encoders" / "sage-mean.py").write_text(SAGE_MEAN)
+    cell = f"{name}.host"
     cfg = json.loads((chip / "configs" / "lightgcn-ub.json").read_text())
-    cfg["name"] = "sage-ub"
-    cfg["model"]["gnn_type"] = "sage-mean"
-    (chip / "configs" / "sage-ub.json").write_text(json.dumps(cfg))
-    (chip / "limits" / "sage-ub.host.json").write_text(
+    cfg["name"] = name
+    cfg["model"].update(model)
+    (chip / "configs" / f"{name}.json").write_text(json.dumps(cfg))
+    (chip / "limits" / f"{cell}.json").write_text(
         (chip / "limits" / "lightgcn-ub.host.json").read_text())
     bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["configs"].append({"name": "sage-ub", "source": "test",
-                             "file": "benchmarks/chip/configs/sage-ub.json",
+    bench["configs"].append({"name": name, "source": "test",
+                             "file": f"benchmarks/chip/configs/{name}.json",
                              "reduced": [], "why": "test"})
-    bench["workloads"].append({"name": "sage-ub.host", "config": "sage-ub",
+    bench["workloads"].append({"name": cell, "config": name,
                                "traffic": "host", "chips": 1,
                                "why": "test"})
     for m in bench["end_to_end"] + bench["per_layer"]:
         if m["name"] in ("pairs_per_s", "train.mfu"):
-            m["workloads"].append("sage-ub.host")
+            m["workloads"].append(cell)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return cell
+
+
+def _add_sage_mean(root: Path) -> str:
+    """GraphSAGE-mean as core/gnn.py computes it, relu([h_self, masked
+    mean] @ w) with w of shape (2d, d), in a cell on the host mix."""
+    chip = root / "benchmarks" / "chip"
+    (chip / "encoders" / "sage-mean.py").write_text(SAGE_MEAN)
+    return _add_host_cell(root, "sage-ub", gnn_type="sage-mean")
 
 
 class _NamedChip:
@@ -129,36 +142,111 @@ class _NamedChip:
         return {}
 
 
-def test_zoo_encoder_enters_as_files(tmp_path, monkeypatch):
-    root = conftest.make_root(tmp_path)
-    chip = root / "benchmarks" / "chip"
-    before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
-    _add_sage_mean(root)
-    for p, data in before.items():
-        assert p.read_bytes() == data, f"{p} was edited"
-    out = conftest.run_cell(root, "sage-ub.host")
-    assert out["correct"] is True, out["checks"]
-    assert set(out["metrics"]) == {"pairs_per_s", "setup_s"}
-    # d = 64, fanouts (4, 3): 6 parents, 20 edges; per parent 4d^2 + 2d
-    # and the residual's 3d, per edge d
-    cfg = json.loads((chip / "configs" / "sage-ub.json").read_text())
-    enc = harness.encoder(cfg["model"], chip)
-    assert counts.tower_flops(cfg["model"], enc) == (
-        6 * (4 * 64 * 64 + 2 * 64 + 3 * 64) + 20 * 64)
-    # the CPU's trace has no device plane: the traced run reads one made up
+def _traced_on_named_chip(root: Path, workload: str, monkeypatch):
+    """A traced run on the CPU taken for a v5e: the CPU's trace has no
+    device plane, so the run reads one made up."""
     monkeypatch.setattr(harness.Traced, "reduce", lambda self, **kw: {
         "busy_s": 1.0, "window_s": 2.0, "modules": {}, "ops": {},
         "top_ops": [], "idle": []})
-    traced = harness.run(
-        ["--workload", "sage-ub.host", "--seed", "7", "--seconds", "0.5",
-         "--trace", "1"], 0.0, root, cache=chip / ".cache",
+    return harness.run(
+        ["--workload", workload, "--seed", "7", "--seconds", "0.5",
+         "--trace", "1"], 0.0, root, cache=root / "benchmarks/chip/.cache",
         chip_check=lambda n: [_NamedChip()])
+
+
+def _unedited(root: Path, add):
+    """Runs ``add(root)`` and checks that it edited no file of the
+    benchmark; what ``add`` returns."""
+    chip = root / "benchmarks" / "chip"
+    before = {p: p.read_bytes() for p in chip.rglob("*") if p.is_file()}
+    out = add(root)
+    for p, data in before.items():
+        assert p.read_bytes() == data, f"{p} was edited"
+    return out
+
+
+def test_zoo_encoder_enters_as_files(tmp_path, monkeypatch):
+    root = conftest.make_root(tmp_path)
+    chip = root / "benchmarks" / "chip"
+    cell = _unedited(root, _add_sage_mean)
+    out = conftest.run_cell(root, cell)
+    assert out["correct"] is True, out["checks"]
+    assert set(out["metrics"]) == {"pairs_per_s", "setup_s"}
+    # d = 64, fanouts (4, 3), one live relation of two: 6 parents, 20
+    # edges; per parent 4d^2 + 2d and the residual's 3d, per edge d
+    cfg = json.loads((chip / "configs" / "sage-ub.json").read_text())
+    enc = harness.encoder(cfg["model"], chip)
+    assert counts.tower_flops(cfg["model"], enc,
+                              conftest.full_tower([4, 3], 2)) == (
+        6 * (4 * 64 * 64 + 2 * 64 + 3 * 64) + 20 * 64)
+    traced = _traced_on_named_chip(root, cell, monkeypatch)
     assert traced["correct"] is True
     assert traced["metrics"]["train.mfu"]["value"] > 0
 
 
+UB8 = [f"{a}2{b}2{c}" for b in ("click", "cart", "fav", "buy")
+       for a, c in (("u", "i"), ("i", "u"))]
+
+
+def _lightgcn_uniform_flops(levels, fanouts, R, d):
+    """LightGCN's needed forward FLOPs under the uniform mix, slot by slot:
+    per live parent the residual (3d) and the n - 1 adds of its n live
+    relations; per live relation a divide per dim; per live child an add
+    per dim."""
+    total = 0
+    for layer in range(len(fanouts)):
+        for k in range(len(fanouts) - layer):
+            par = levels[k]
+            kids = levels[k + 1].reshape(*par.shape, R, fanouts[k])
+            for b, w in zip(*np.nonzero(par >= 0)):
+                live = [int((kids[b, w, r] >= 0).sum()) for r in range(R)]
+                n = sum(1 for m in live if m)
+                total += 3 * d + max(n - 1, 0) * d + n * d + sum(live) * d
+    return total
+
+
+def test_eight_relations_enter_as_files(tmp_path, monkeypatch):
+    root = conftest.make_root(tmp_path)
+    cell = _unedited(root, lambda r: _add_host_cell(
+        r, "lightgcn-ub8", relations=UB8))
+    out = conftest.run_cell(root, cell)
+    assert out["correct"] is True, out["checks"]
+    # what the reference checked, and what the mfu reader was given
+    checked, layers = [], []
+    train_steps = reference.train_steps
+
+    def spy_steps(params0, model, enc, opt, batches, *a):
+        checked.append([b["towers"] for b in batches])
+        return train_steps(params0, model, enc, opt, batches, *a)
+
+    monkeypatch.setattr(reference, "train_steps", spy_steps)
+    load = harness.load_module
+
+    def spy_load(path, name):
+        mod = load(path, name)
+        if name == "bench_metric_train_mfu":
+            read = mod.read
+            mod.read = lambda layer: layers.append(layer) or read(layer)
+        return mod
+
+    monkeypatch.setattr(harness, "load_module", spy_load)
+    traced = _traced_on_named_chip(root, cell, monkeypatch)
+    assert traced["correct"] is True
+    fan, d, P = [4, 3], 64, layers[0]["pairs"]
+    # towers of 801 slots (level 2: 32 x 8 x 3), with parents that mix
+    # more than one live relation
+    assert checked[0][0][2].shape[1] == 4 * 8 * 8 * 3
+    assert max(counts.tower_work(checked[0][0], fan, 8)[2]) >= 2
+    fwd = np.mean([_lightgcn_uniform_flops(t, fan, 8, d)
+                   for t in checked[0]])
+    flops = 3 * (fwd + 2 * P * P * d + 4 * P * P)
+    want = 100 * flops * layers[0]["pairs_per_s"] / P / 197e12
+    assert traced["metrics"]["train.mfu"]["value"] == pytest.approx(
+        want, rel=1e-12)
+
+
 @pytest.mark.parametrize("change,named", [
-    ({"relation_agg": "gatne"}, "mixes/gatne.py"),
+    ({"relation_agg": "no-such-mix"}, "mixes/no-such-mix.py"),
     ({"side_info": True}, "side_info"),
 ])
 def test_unmodelled_encoder_ends_before_the_graph(tmp_path, monkeypatch,
